@@ -19,7 +19,7 @@ from typing import TYPE_CHECKING, Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro import sanitize, telemetry
+from repro import device, sanitize, telemetry
 
 if TYPE_CHECKING:
     from .plan import TablePlan
@@ -61,6 +61,7 @@ _C_SPILL_BLOCKS = telemetry.counter("repro.residency.spill.blocks")
 _C_FAULT_BLOCKS = telemetry.counter("repro.residency.fault_in.blocks")
 _H_REWRITE = telemetry.histogram("repro.store.rewrite")
 _C_MIGRATED = telemetry.counter("repro.store.migrate.rows")
+_C_PALLAS_DOWNGRADE = telemetry.counter("repro.plan.pallas_downgrade")
 
 
 @dataclasses.dataclass
@@ -389,7 +390,8 @@ class TableCodec:
 
         Every indexed row must have been encoded on the fast path (its codes
         follow the plan's fixed slot layout).  ``backend`` is ``"numpy"`` or
-        ``"pallas"`` (interpret mode on CPU, verified against numpy).
+        ``"pallas"`` (compiled on a TPU, interpret mode on CPU, verified
+        against numpy).
         """
         plan = self.compile()
         if plan is None:
@@ -472,8 +474,7 @@ class CompressedTable:
     with one ``decode_select`` call (no per-tuple Python loop) and falls back
     to scalar block decode for the rest.  ``use_pallas`` selects the kernel
     backend for large fast batches: ``None`` auto-detects (kernel only on a
-    non-CPU jax backend), ``True`` forces it (interpret mode on CPU),
-    ``False`` disables it.
+    TPU), ``True`` forces it (interpret mode on CPU), ``False`` disables it.
     """
 
     PALLAS_MIN_ROWS = 4096  # auto mode: below this, numpy always wins
@@ -1160,24 +1161,25 @@ class CompressedTable:
     def _resolve_backend(
         self, backend: Optional[str], n_rows: int, codec: Optional[TableCodec] = None
     ) -> str:
+        """The decode backend for ``n_rows`` fast rows under ``codec``.
+
+        An explicit ``"pallas"`` (the argument, or ``use_pallas=True`` when
+        the argument is None) runs the kernel when the plan can; a plan
+        with conditional slots cannot, and that downgrade to numpy is
+        counted (``repro.plan.pallas_downgrade``).  Auto mode picks the
+        kernel only for large batches on a TPU (:mod:`repro.device`).
+        """
         plan = (codec or self.codec).compile()
-        if backend in ("numpy", "pallas"):
-            # Explicit request; quietly downgrade when the plan has
-            # conditional slots the kernel cannot run.
-            if backend == "pallas" and (plan is None or not plan.pallas_ok):
-                return "numpy"
-            return backend
-        if plan is None or not plan.pallas_ok or self.use_pallas is False:
+        eligible = plan is not None and plan.pallas_ok
+        if backend == "pallas" or (backend is None and self.use_pallas):
+            if eligible:
+                return "pallas"
+            _C_PALLAS_DOWNGRADE.inc()
             return "numpy"
-        if self.use_pallas:
+        if backend == "numpy" or not eligible or self.use_pallas is False:
+            return "numpy"
+        if n_rows >= self.PALLAS_MIN_ROWS and not device.interpret_default():
             return "pallas"
-        if n_rows >= self.PALLAS_MIN_ROWS:  # auto: only off-CPU is it a win
-            try:
-                import jax
-                if jax.default_backend() != "cpu":
-                    return "pallas"
-            except Exception:  # pragma: no cover - jax always present here
-                pass
         return "numpy"
 
     def get_many(

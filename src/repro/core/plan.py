@@ -759,15 +759,17 @@ class TablePlan:
         if not self.pallas_ok:
             raise PlanFallback("plan has conditional slots; Pallas ineligible")
         import jax.numpy as jnp
-        from repro.kernels.delayed_decode import delayed_decode
+        from repro.kernels.delayed_decode import BLOCK_T, delayed_decode
         rows = np.asarray(rows, np.int64)
         if rows.size == 0:
             return np.zeros((0, self.S), np.int64)
-        # Pad the batch to a pow2 bucket (floor 8) so jax traces one
-        # kernel per bucket instead of one per distinct batch size — the
-        # same bucketing the prepared-op cache keys on (DESIGN.md §11).
+        # Pad the batch to a pow2 bucket so jax compiles one kernel per
+        # bucket instead of one per distinct batch size.  The floor is the
+        # kernel's row tile: every batch up to BLOCK_T rows shares one
+        # compiled program (the prepared-op cache keys on finer buckets,
+        # DESIGN.md §11).
         n = rows.size
-        padded = 1 << max(3, (n - 1).bit_length())
+        padded = max(BLOCK_T, 1 << (n - 1).bit_length())
         if padded != n:
             rows = np.concatenate([rows, np.full(padded - n, rows[-1], np.int64)])
         starts = offsets[rows]
@@ -783,7 +785,7 @@ class TablePlan:
         return np.asarray(out).astype(np.int64)[:n]
 
     def pallas_tables(self) -> Tuple[Any, int]:
-        """Lazy ``(tables f32[S, M, 7], m_bits)`` in the kernel's layout."""
+        """Lazy ``(tables f32[R, 7 * 128], m_bits)`` in the kernel's layout."""
         if self._tables is None:
             t0 = telemetry.clock()
             from repro.kernels.ops import pack_slot_tables

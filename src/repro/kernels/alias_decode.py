@@ -12,11 +12,13 @@ Block layout: codes are tiled into (BLOCK,) vectors over a 1-D grid; the
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro import device
 
 TOTAL_BITS = 16
 BLOCK = 1024
@@ -45,7 +47,7 @@ def _alias_kernel(m_bits: int, codes_ref, table_ref, sym_ref, a_ref, k_ref):
 
 @functools.partial(jax.jit, static_argnames=("m_bits", "interpret"))
 def alias_decode(
-    codes: jax.Array, table: jax.Array, m_bits: int, interpret: bool = True
+    codes: jax.Array, table: jax.Array, m_bits: int, interpret: Optional[bool] = None
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """codes int32[N] + table f32[M, 7] -> (sym, a, k) int32[N]."""
     N = codes.shape[0]
@@ -65,6 +67,6 @@ def alias_decode(
         ],
         out_specs=[pl.BlockSpec((BLOCK,), lambda i: (i,))] * 3,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=device.kernel_interpret(interpret),
     )(codes_p, table)
     return sym[:N], a[:N], k[:N]

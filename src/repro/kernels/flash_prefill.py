@@ -14,11 +14,14 @@ arithmetic.  GQA layout: q [B, Sq, K, G, D], k/v [B, Sk, K, D].
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro import device
 
 NEG = -1e30
 
@@ -92,7 +95,7 @@ def flash_prefill_attention(
     window: int = 0,
     q_block: int = 512,
     kv_chunk: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """q: [B, Sq, H, D]; k/v: [B, Sk, K, D].  Returns [B, Sq, H, D]."""
     B, Sq, H, D = q.shape
@@ -122,6 +125,6 @@ def flash_prefill_attention(
             pltpu.VMEM((B, qb, K, G), jnp.float32),
             pltpu.VMEM((B, qb, K, G), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=device.kernel_interpret(interpret),
     )(qr, kr, vr)
     return out[:, :Sq].reshape(B, Sq, H, D)

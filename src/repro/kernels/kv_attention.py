@@ -13,11 +13,14 @@ q: [B, H, D]; kq/vq: int8[B, S, K, D]; scales f32[B, S, K].
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro import device
 
 NEG = -1e30
 CHUNK = 512
@@ -80,7 +83,7 @@ def kv_attention_int8(
     vs: jax.Array,
     length_static: int,
     chunk: int = CHUNK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Flash-decoding over int8 KV. Returns [B, H, D] float32.
 
@@ -111,6 +114,6 @@ def kv_attention_int8(
             pltpu.VMEM((B, K, G), jnp.float32),      # running max
             pltpu.VMEM((B, K, G), jnp.float32),      # running denom
         ],
-        interpret=interpret,
+        interpret=device.kernel_interpret(interpret),
     )(qr, kq, ks, vq, vs)
     return out.reshape(B, H, D)

@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only (TPU v5e
-is the compile *target*); on real TPUs callers pass ``interpret=False``.
-Helpers convert host-side coder objects into the dense device table layout.
+``interpret`` defaults to the platform's choice (:mod:`repro.device`): the
+kernels compile for the chip on a TPU and run in the Pallas interpreter on
+the CPU.  Helpers convert host-side coder objects into the dense device
+table layout.
 """
 
 from __future__ import annotations
@@ -15,7 +16,7 @@ import numpy as np
 from repro.core.coders import DiscreteCoder, UniformCoder
 from . import ref as ref_lib
 from .alias_decode import alias_decode
-from .delayed_decode import delayed_decode
+from .delayed_decode import LANES, N_FIELDS, delayed_decode, slot_layout
 from .flash_prefill import flash_prefill_attention
 from .kv_attention import kv_attention_int8
 
@@ -30,12 +31,14 @@ __all__ = [
 
 
 def pack_slot_tables(coders: Sequence) -> Tuple[jnp.ndarray, Tuple[int, ...]]:
-    """Stack per-slot decode tables into [S, M_max, 7] (padded) + m_bits.
+    """Pack per-slot decode tables into the kernel's [R, 7 * 128] layout.
 
     Accepts a mix of :class:`DiscreteCoder` (alias layout, Appendix C) and
     :class:`UniformCoder` (contiguous segments) — both lower to the same
     bucket-major (threshold, sym_u, sym_v, ja, jb, k_u, k_v) row format the
-    delayed-decode kernel consumes.
+    delayed-decode kernel consumes.  Each slot keeps its own bucket count:
+    bucket ``p`` of slot ``s`` lands at row ``offset_s + (p >> 7)``, lane
+    ``field * 128 + (p & 127)`` (:func:`~.delayed_decode.slot_layout`).
     """
     tabs: List[np.ndarray] = []
     mbits: List[int] = []
@@ -48,11 +51,15 @@ def pack_slot_tables(coders: Sequence) -> Tuple[jnp.ndarray, Tuple[int, ...]]:
             raise TypeError(f"cannot pack device tables for {type(c).__name__}")
         tabs.append(np.asarray(t))
         mbits.append(m)
-    M = max(t.shape[0] for t in tabs)
-    out = np.zeros((len(tabs), M, 7), np.float32)
-    for i, t in enumerate(tabs):
-        out[i, :t.shape[0]] = t
-    return jnp.asarray(out), tuple(mbits)
+    layout, n_rows = slot_layout(tuple(mbits))
+    out = np.zeros((n_rows, N_FIELDS, LANES), np.float32)
+    for t, (off, _) in zip(tabs, layout):
+        M = t.shape[0]
+        rows = -(-M // LANES)
+        blk = np.zeros((rows * LANES, N_FIELDS), np.float32)
+        blk[:M] = t
+        out[off:off + rows] = blk.reshape(rows, LANES, N_FIELDS).transpose(0, 2, 1)
+    return jnp.asarray(out.reshape(n_rows, N_FIELDS * LANES)), tuple(mbits)
 
 
 def dense_codes(codes: np.ndarray, offsets: np.ndarray, n_slots: int) -> np.ndarray:

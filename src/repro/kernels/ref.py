@@ -85,10 +85,14 @@ def delayed_decode_ref(
     """Batched delayed decoding (Algorithm 5), division-free uint32 math.
 
     codes_dense: int32[T, S] physical codes, left-justified per tuple.
-    tables: float32[S, M, 7] per-slot alias tables (padded to max M).
+    tables: float32[R, 7 * 128] packed slot tables (``ops.pack_slot_tables``).
     Returns syms int32[T, S].
     """
+    from .delayed_decode import LANES, N_FIELDS, slot_layout
+
     T, S = codes_dense.shape
+    layout, _ = slot_layout(tuple(m_bits))
+    fields = tables.reshape(tables.shape[0], N_FIELDS, LANES).transpose(0, 2, 1)
     v_info = jnp.zeros((T,), jnp.uint32)
     v_size = jnp.ones((T,), jnp.uint32)
     pending = jnp.zeros((T,), bool)
@@ -100,7 +104,9 @@ def delayed_decode_ref(
         stream = jnp.take_along_axis(codes_dense, cursor[:, None], axis=1)[:, 0]
         code = jnp.where(pending, pend_code, stream)
         cursor = cursor + jnp.where(pending, 0, 1)
-        sym, a, k = alias_decode_ref(code, tables[s], m_bits[s])
+        off, n = layout[s]
+        slot_tab = fields[off:off + n].reshape(n * LANES, N_FIELDS)
+        sym, a, k = alias_decode_ref(code, slot_tab, m_bits[s])
         out.append(sym)
         ku = k.astype(jnp.uint32)
         v_info = v_info * ku + a.astype(jnp.uint32)   # exact: result < 2**32

@@ -31,6 +31,7 @@ METRICS: Tuple[str, ...] = (
     "repro.plan.cache.pallas_miss",
     "repro.plan.compile",
     "repro.plan.compile.pallas_jit",
+    "repro.plan.pallas_downgrade",
     "repro.plan.pallas_pack",
     "repro.plan.pallas_pack.events",
     # -- residency / out-of-core tier ------------------------------------
