@@ -3,8 +3,8 @@
 * **Interpret or compile.**  A Pallas kernel compiles for the chip when
   JAX's default backend is a TPU and runs in the Pallas interpreter
   everywhere else (the CPU test runs).  The decision is made once per
-  process; a kernel never runs in the interpreter on a TPU — asking for
-  it there is an error, not a quiet fallback.
+  process, and no kernel entry point takes the flag from its caller, so a
+  kernel never runs in the interpreter on a TPU.
 * **Compile cache.**  Kernels compile for seconds each, so JAX's
   persistent compilation cache is pinned before the first kernel
   compiles: ``JAX_COMPILATION_CACHE_DIR`` when it is set (JAX reads the
@@ -19,7 +19,6 @@ from __future__ import annotations
 import functools
 import os
 import pathlib
-from typing import Optional
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 CHECKOUT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
@@ -34,23 +33,14 @@ def platform() -> str:
 
 @functools.lru_cache(maxsize=None)
 def interpret_default() -> bool:
-    """True off-TPU (Pallas interpreter), False on a TPU (compiled)."""
-    return platform() != "tpu"
+    """True off-TPU (Pallas interpreter), False on a TPU (compiled).
 
-
-def kernel_interpret(interpret: Optional[bool] = None) -> bool:
-    """Resolve a kernel's ``interpret`` argument against the platform.
-
-    ``None`` takes :func:`interpret_default`.  Every kernel entry point
-    calls this, so it also pins the compile cache before the first
-    kernel compiles.
+    Every kernel entry point takes its ``interpret`` flag from here (none
+    accepts one from its caller), so this also pins the compile cache
+    before the first kernel compiles.
     """
     configure_compile_cache()
-    if interpret is None:
-        return interpret_default()
-    if interpret and not interpret_default():
-        raise ValueError("Pallas interpret mode requested on a TPU")
-    return bool(interpret)
+    return platform() != "tpu"
 
 
 def compile_cache_dir() -> str:

@@ -48,8 +48,11 @@ VERBS = ("insert", "get", "update", "delete")
 
 
 def batch_bucket(n: int) -> int:
-    """Pow2 batch-size bucket (floor 8): the padded size the lowered
-    entry — and the Pallas decode trace underneath — is keyed by."""
+    """Pow2 batch-size bucket (floor 8) the lowered entry is keyed by.
+
+    The Pallas decode underneath pads to the same pow2 but with a floor of
+    its 256-row tile, so every bucket up to 256 shares one kernel compile
+    (``TablePlan._decode_select_pallas``)."""
     return 1 << max(3, (max(1, n) - 1).bit_length())
 
 

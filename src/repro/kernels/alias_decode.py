@@ -12,7 +12,7 @@ Block layout: codes are tiled into (BLOCK,) vectors over a 1-D grid; the
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,9 +45,9 @@ def _alias_kernel(m_bits: int, codes_ref, table_ref, sym_ref, a_ref, k_ref):
     k_ref[...] = k
 
 
-@functools.partial(jax.jit, static_argnames=("m_bits", "interpret"))
+@functools.partial(jax.jit, static_argnames=("m_bits",))
 def alias_decode(
-    codes: jax.Array, table: jax.Array, m_bits: int, interpret: Optional[bool] = None
+    codes: jax.Array, table: jax.Array, m_bits: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """codes int32[N] + table f32[M, 7] -> (sym, a, k) int32[N]."""
     N = codes.shape[0]
@@ -67,6 +67,6 @@ def alias_decode(
         ],
         out_specs=[pl.BlockSpec((BLOCK,), lambda i: (i,))] * 3,
         out_shape=out_shape,
-        interpret=device.kernel_interpret(interpret),
+        interpret=device.interpret_default(),
     )(codes_p, table)
     return sym[:N], a[:N], k[:N]

@@ -26,7 +26,7 @@ count, so one wide slot does not pad the others.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Set, Tuple
+from typing import Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -164,15 +164,14 @@ def delayed_decode(
     codes_dense: jax.Array,
     tables: jax.Array,
     m_bits: Tuple[int, ...],
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """codes int32[T, S] + packed tables f32[R, 7*128] -> syms int32[T, S].
 
     Thin telemetry shim over the jitted kernel: counts plan-cache
     hits/misses per trace signature and books first-call (compile) time.
-    ``interpret`` defaults to the platform's choice (:mod:`repro.device`).
+    Interpret or compile is the platform's choice (:mod:`repro.device`).
     """
-    interpret = device.kernel_interpret(interpret)
+    interpret = device.interpret_default()
     sig = (codes_dense.shape, tables.shape, tuple(m_bits), interpret)
     if sig in _SEEN_SIGS:
         _C_JIT_HIT.inc()

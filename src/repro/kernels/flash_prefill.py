@@ -14,7 +14,6 @@ arithmetic.  GQA layout: q [B, Sq, K, G, D], k/v [B, Sk, K, D].
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -85,7 +84,7 @@ def _flash_kernel(
 
 
 @ functools.partial(
-    jax.jit, static_argnames= ("causal", "window", "q_block", "kv_chunk", "interpret")
+    jax.jit, static_argnames= ("causal", "window", "q_block", "kv_chunk")
 )
 def flash_prefill_attention(
     q: jax.Array,
@@ -95,7 +94,6 @@ def flash_prefill_attention(
     window: int = 0,
     q_block: int = 512,
     kv_chunk: int = 512,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """q: [B, Sq, H, D]; k/v: [B, Sk, K, D].  Returns [B, Sq, H, D]."""
     B, Sq, H, D = q.shape
@@ -125,6 +123,6 @@ def flash_prefill_attention(
             pltpu.VMEM((B, qb, K, G), jnp.float32),
             pltpu.VMEM((B, qb, K, G), jnp.float32),
         ],
-        interpret=device.kernel_interpret(interpret),
+        interpret=device.interpret_default(),
     )(qr, kr, vr)
     return out[:, :Sq].reshape(B, Sq, H, D)

@@ -13,7 +13,6 @@ q: [B, H, D]; kq/vq: int8[B, S, K, D]; scales f32[B, S, K].
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -74,7 +73,7 @@ def _kv_attn_kernel(
         o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None])
 
 
-@ functools.partial(jax.jit, static_argnames= ("length_static", "interpret", "chunk"))
+@ functools.partial(jax.jit, static_argnames= ("length_static", "chunk"))
 def kv_attention_int8(
     q: jax.Array,
     kq: jax.Array,
@@ -83,7 +82,6 @@ def kv_attention_int8(
     vs: jax.Array,
     length_static: int,
     chunk: int = CHUNK,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Flash-decoding over int8 KV. Returns [B, H, D] float32.
 
@@ -114,6 +112,6 @@ def kv_attention_int8(
             pltpu.VMEM((B, K, G), jnp.float32),      # running max
             pltpu.VMEM((B, K, G), jnp.float32),      # running denom
         ],
-        interpret=device.kernel_interpret(interpret),
+        interpret=device.interpret_default(),
     )(qr, kq, ks, vq, vs)
     return out.reshape(B, H, D)

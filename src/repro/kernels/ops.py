@@ -1,6 +1,6 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to the platform's choice (:mod:`repro.device`): the
+Interpret or compile is the platform's choice (:mod:`repro.device`): the
 kernels compile for the chip on a TPU and run in the Pallas interpreter on
 the CPU.  Helpers convert host-side coder objects into the dense device
 table layout.

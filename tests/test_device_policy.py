@@ -55,18 +55,27 @@ class TestInterpretDecision:
     def test_cpu_interprets(self):
         assert jax.default_backend() == "cpu"
         assert device.interpret_default() is True
-        assert device.kernel_interpret(None) is True
 
     def test_tpu_compiles(self, on_platform):
         on_platform("tpu")
         assert device.interpret_default() is False
-        assert device.kernel_interpret(None) is False
-        assert device.kernel_interpret(False) is False
 
-    def test_interpreter_refused_on_tpu(self, on_platform):
-        on_platform("tpu")
-        with pytest.raises(ValueError, match="interpret"):
-            device.kernel_interpret(True)
+    def test_interpreter_refused_on_tpu(self):
+        """No kernel entry point takes ``interpret`` from its caller, so
+        nothing can ask for the interpreter on a TPU: the flag comes from
+        the platform policy alone."""
+        import importlib
+        import inspect
+
+        for entry in ("delayed_decode:delayed_decode",
+                      "alias_decode:alias_decode",
+                      "flash_prefill:flash_prefill_attention",
+                      "kv_attention:kv_attention_int8"):
+            module, name = entry.split(":")
+            mod = importlib.import_module(f"repro.kernels.{module}")
+            params = inspect.signature(getattr(mod, name)).parameters
+            assert "interpret" not in params, entry
+            assert "device.interpret_default()" in inspect.getsource(mod), entry
 
     def test_engine_decode_takes_the_platform_choice(self, on_platform,
                                                      monkeypatch):

@@ -6,8 +6,11 @@ footprint exceeds the scoped limit.  These tests hand the TPU compiler
 the kernel's real argument shapes for every TPC-C table (slot counts and
 per-slot bucket bits taken from a small fitted database, which already
 holds the widest slots: the 2**16-bucket ``w_ytd`` and the 2**13/2**14
-customer slots) at the 256-row bucket and one larger bucket.  Nothing
-runs; a passing compile is not a chip run.
+customer slots) at the 256-row bucket and one larger bucket.  Bucket
+counts grow with the data, so the per-slot bucket bits of one warehouse
+at TPC-C spec cardinalities (the ``chip_smoke.py`` database) are pinned
+below and compiled too.  Nothing runs; a passing compile is not a chip
+run.
 
 The topology is described inside a fixture, never at import, so every
 test worker collects the same tests and only the worker given this file
@@ -31,6 +34,29 @@ from repro.oltp import tpcc  # noqa: E402
 
 TABLES = sorted(tpcc.TPCC_TABLES)
 BUCKETS = (256, 4096)
+
+# Per-slot bucket bits (``plan.pallas_tables()[1]``) of one warehouse
+# loaded at spec cardinalities with seed 0: 10 districts of 3,000
+# customers, 100,000 items and stock rows, 3,000 orders per district.
+SPEC_M_BITS = {
+    "warehouse": (1, 1, 3, 1, 2, 4, 4, 4, 4, 1, 2, 1, 2, 1, 1, 1, 1, 10, 2,
+                  16),
+    "district": (1, 4, 4, 3, 1, 3, 4, 4, 4, 4, 1, 3, 1, 3, 3, 4, 4, 9, 2, 10,
+                 15, 9, 7),
+    "customer": (1, 4, 9, 3, 6, 3, 1, 5, 4, 4, 4, 4, 1, 5, 1, 5, 4, 6, 9, 3,
+                 1, 2, 3, 2, 4, 4, 4, 4, 3, 2, 3, 2, 4, 4, 4, 4, 3, 2, 4, 4,
+                 4, 4, 4, 10, 13, 10, 14, 9, 4, 3, 2, 5, 1, 5, 1, 5, 1, 5, 1,
+                 5, 4, 4, 4, 4, 4),
+    "item": (9, 8, 9, 5, 3, 1, 6, 1, 6, 1, 6, 9, 5, 3, 2, 5, 1, 5, 1, 5, 1,
+             5, 4, 4, 4, 4, 4),
+    "stock": (1, 9, 8, 9, 1, 9, 1, 9, 4, 3, 1, 2, 2, 2, 2, 2, 4, 4, 4, 2, 2,
+              4, 4, 4, 2, 2, 4, 4, 4, 4, 4, 3, 1, 2, 2, 2, 2, 2, 4, 4, 4, 2,
+              2, 4, 4, 4, 2, 2, 4, 4, 4, 4, 4, 3, 2, 5, 1, 5, 1, 5, 1, 5, 1,
+              5),
+    "orders": (1, 4, 9, 7, 9, 3, 9, 4, 4, 1),
+    "order_line": (1, 4, 9, 7, 4, 9, 8, 1, 9, 6, 4, 9, 8, 3, 1, 2, 2, 2, 2,
+                   2, 4, 4, 4, 2, 2, 4, 4, 4, 2, 2, 4, 4, 4, 4, 4),
+}
 
 
 @pytest.fixture(scope="module")
@@ -82,11 +108,7 @@ def test_widest_slots_are_present(m_bits_by_table):
     assert len(m_bits_by_table["stock"]) >= 60
 
 
-@pytest.mark.parametrize("rows", BUCKETS)
-@pytest.mark.parametrize("table", TABLES)
-def test_decode_kernel_compiles_for_v5e(table, rows, m_bits_by_table, one_chip,
-                                        no_persistent_cache):
-    m_bits = m_bits_by_table[table]
+def _compile(m_bits, rows, one_chip):
     _, n_rows = slot_layout(m_bits)
     codes = jax.ShapeDtypeStruct((rows, len(m_bits)), jnp.int32,
                                  sharding=one_chip)
@@ -95,3 +117,27 @@ def test_decode_kernel_compiles_for_v5e(table, rows, m_bits_by_table, one_chip,
     compiled = _delayed_decode_jit.lower(
         codes, tables, m_bits=m_bits, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+@pytest.mark.parametrize("table", TABLES)
+def test_decode_kernel_compiles_for_v5e(table, rows, m_bits_by_table, one_chip,
+                                        no_persistent_cache):
+    _compile(m_bits_by_table[table], rows, one_chip)
+
+
+def test_spec_scale_shapes_cover_every_table(m_bits_by_table):
+    """The pinned spec-scale plans are the small database's plans with
+    wider buckets: one entry per table, the same widest slots."""
+    assert sorted(SPEC_M_BITS) == TABLES
+    assert max(SPEC_M_BITS["warehouse"]) == 16
+    assert max(SPEC_M_BITS["customer"]) >= max(m_bits_by_table["customer"])
+    for name in TABLES:
+        assert abs(len(SPEC_M_BITS[name]) - len(m_bits_by_table[name])) <= 2
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+@pytest.mark.parametrize("table", TABLES)
+def test_decode_kernel_compiles_for_v5e_at_spec_scale(table, rows, one_chip,
+                                                      no_persistent_cache):
+    _compile(SPEC_M_BITS[table], rows, one_chip)
