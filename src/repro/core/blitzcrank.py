@@ -47,19 +47,13 @@ from .models import (
 )
 from .structure import discretize_column, learn_order
 
-# Telemetry handles (DESIGN.md §9).  Scalar encode/decode and
-# spill/fault-in are leaf phases of the wall-time breakdown; plan-cache
-# hit/miss and maintenance verbs are counters the gap hunt reads.
-_H_ENC_SCALAR = telemetry.histogram("repro.core.encode.scalar_block")
-_H_DEC_SCALAR = telemetry.histogram("repro.core.decode.scalar_block")
-_H_COMPILE = telemetry.histogram("repro.plan.compile")
+# Telemetry handles (DESIGN.md §9).  The scalar encode/decode and
+# spill/fault-in spans are leaf phases of the wall-time breakdown;
+# plan-cache hit/miss and maintenance verbs are counters the gap hunt reads.
 _C_PLAN_HIT = telemetry.counter("repro.plan.cache.hit")
 _C_PLAN_MISS = telemetry.counter("repro.plan.cache.miss")
-_H_SPILL = telemetry.histogram("repro.residency.spill")
-_H_FAULT = telemetry.histogram("repro.residency.fault_in")
 _C_SPILL_BLOCKS = telemetry.counter("repro.residency.spill.blocks")
 _C_FAULT_BLOCKS = telemetry.counter("repro.residency.fault_in.blocks")
-_H_REWRITE = telemetry.histogram("repro.store.rewrite")
 _C_MIGRATED = telemetry.counter("repro.store.migrate.rows")
 _C_PALLAS_DOWNGRADE = telemetry.counter("repro.plan.pallas_downgrade")
 
@@ -263,15 +257,14 @@ class TableCodec:
         if not self._plan_tried or force:
             self._plan_tried = True
             _C_PLAN_MISS.inc()
-            t0 = telemetry.clock()
-            from .plan import PlanFallback, compile_plan
-            try:
-                self._plan = compile_plan(self)
-                self._plan_reason = None
-            except PlanFallback as e:
-                self._plan = None
-                self._plan_reason = str(e)
-            _H_COMPILE.observe_since(t0)
+            with telemetry.span("repro.plan.compile"):
+                from .plan import PlanFallback, compile_plan
+                try:
+                    self._plan = compile_plan(self)
+                    self._plan_reason = None
+                except PlanFallback as e:
+                    self._plan = None
+                    self._plan_reason = str(e)
         else:
             _C_PLAN_HIT.inc()
         return self._plan
@@ -303,17 +296,16 @@ class TableCodec:
                 m.reset_block()
 
     def _scalar_compress(self, rows: Sequence[Dict[str, Any]]) -> np.ndarray:
-        t0 = telemetry.clock()
-        self._reset_block_state()
-        enc = BlockEncoder()
-        # blitzlint: waive[BL001] -- scalar encode chains each model on the previous column value (sequential by design)
-        for r in rows:
-            ctx: Dict[str, Any] = {}
-            for name in self.order:
-                self.models[name].encode_value(r[name], enc, ctx)
-                ctx[name] = r[name]
-        codes = delayed.encode_block(enc.slots, self.lam)
-        _H_ENC_SCALAR.observe_since(t0)
+        with telemetry.span("repro.core.encode.scalar_block"):
+            self._reset_block_state()
+            enc = BlockEncoder()
+            # blitzlint: waive[BL001] -- scalar encode chains each model on the previous column value (sequential by design)
+            for r in rows:
+                ctx: Dict[str, Any] = {}
+                for name in self.order:
+                    self.models[name].encode_value(r[name], enc, ctx)
+                    ctx[name] = r[name]
+            codes = delayed.encode_block(enc.slots, self.lam)
         return checked_asarray(codes, np.uint16, where="scalar_compress codes")
 
     def compress_block(self, rows: Sequence[Dict[str, Any]]) -> np.ndarray:
@@ -405,18 +397,17 @@ class TableCodec:
         return plan.decode_syms_to_rows(syms)
 
     def decompress_block(self, codes: np.ndarray, n_rows: int) -> List[Dict[str, Any]]:
-        t0 = telemetry.clock()
-        self._reset_block_state()
-        dec = BlockDecoder(
-            codes.tolist() if isinstance(codes, np.ndarray) else codes, self.lam
-        )
-        out = []
-        for _ in range(n_rows):
-            ctx: Dict[str, Any] = {}
-            for name in self.order:
-                ctx[name] = self.models[name].decode_value(dec, ctx)
-            out.append(ctx)
-        _H_DEC_SCALAR.observe_since(t0)
+        with telemetry.span("repro.core.decode.scalar_block"):
+            self._reset_block_state()
+            dec = BlockDecoder(
+                codes.tolist() if isinstance(codes, np.ndarray) else codes, self.lam
+            )
+            out = []
+            for _ in range(n_rows):
+                ctx: Dict[str, Any] = {}
+                for name in self.order:
+                    ctx[name] = self.models[name].decode_value(dec, ctx)
+                out.append(ctx)
         return out
 
     # ------------------------------------------------------------------
@@ -768,25 +759,24 @@ class CompressedTable:
         coalesced segment write of CRC32-framed extents) and mark them
         non-resident.  Their in-memory runs become dead bytes until the
         next rewrite."""
-        t0 = telemetry.clock()
-        res = self._res
-        order = np.argsort(self._offsets[blocks], kind="stable")
-        blocks = blocks[order]
-        starts = self._offsets[blocks]
-        lens = self._offsets[blocks + 1] - starts
-        total = int(lens.sum())
-        payloads = [
-            self.arena[int(s):int(s) + int(ln)].tobytes() for s, ln in zip(starts, lens)
-        ]
-        offs = res.disk.write_many(payloads)
-        self._disk_off[blocks] = np.asarray(offs, dtype=np.int64)
-        self._disk_len[blocks] = lens
-        self._resident[blocks] = False
-        self._dead_codes += total
-        self._spilled_codes += total
-        res.spills += int(blocks.size)
-        _C_SPILL_BLOCKS.add(int(blocks.size))
-        _H_SPILL.observe_since(t0)
+        with telemetry.span("repro.residency.spill"):
+            res = self._res
+            order = np.argsort(self._offsets[blocks], kind="stable")
+            blocks = blocks[order]
+            starts = self._offsets[blocks]
+            lens = self._offsets[blocks + 1] - starts
+            total = int(lens.sum())
+            payloads = [
+                self.arena[int(s):int(s) + int(ln)].tobytes() for s, ln in zip(starts, lens)
+            ]
+            offs = res.disk.write_many(payloads)
+            self._disk_off[blocks] = np.asarray(offs, dtype=np.int64)
+            self._disk_len[blocks] = lens
+            self._resident[blocks] = False
+            self._dead_codes += total
+            self._spilled_codes += total
+            res.spills += int(blocks.size)
+            _C_SPILL_BLOCKS.add(int(blocks.size))
         self.sanitize_boundary("spill_blocks")
 
     def _fault_in(self, blocks: np.ndarray) -> None:
@@ -796,51 +786,50 @@ class CompressedTable:
         decode path then serves them exactly like always-resident blocks —
         a miss costs one read plus one vectorized decode, never per-row
         work."""
-        t0 = telemetry.clock()
-        res = self._res
-        lens = self._disk_len[blocks].copy()
-        offs_old = self._disk_off[blocks].copy()
-        try:
-            payloads = res.disk.read_many_checked(offs_old, 2 * lens)
-        except ExtentCorruptionError as e:
-            # No state was mutated: surface the affected row ids so a
-            # durability layer can rebuild them from the WAL and retry.
-            bad = blocks[np.asarray(e.indices, dtype=np.int64)]
-            res.quarantined += len(e.indices)
-            raise SpillCorruptionError(self._block2row[bad].tolist()) from e
-        total = int(lens.sum())
-        buf = np.empty(total, dtype=np.uint16)
-        pos = 0
-        for j in range(blocks.size):
-            ln = int(lens[j])
-            buf[pos:pos + ln] = np.frombuffer(payloads[j], dtype=np.uint16)
-            pos += ln
-        n = int(blocks.size)
-        base = self.used
-        self._append_codes(buf)
-        self._grow_index(n)
-        first = self.n_blocks
-        self._offsets[first + 1:first + 1 + n] = base + np.cumsum(lens)
-        self._fast[first:first + n] = self._fast[blocks]
-        self._plan_ver[first:first + n] = self._plan_ver[blocks]
-        self._zone_union(first, blocks)
-        rows = self._block2row[blocks]
-        self._init_new_blocks(first, n, rows)
-        self.n_blocks += n
-        self.block_rows.extend([1] * n)
-        self._row2block[rows] = np.arange(first, first + n)
-        # the old slots are orphans now; their disk extents are freed
-        self._block2row[blocks] = -1
-        self._resident[blocks] = True
-        self._disk_off[blocks] = -1
-        self._disk_len[blocks] = 0
-        for o, ln in zip(offs_old.tolist(), lens.tolist()):
-            res.disk.free(o, framed_len(2 * ln))
-        self._spilled_codes -= total
-        res.faults += n
-        res.fault_batches += 1
-        _C_FAULT_BLOCKS.add(n)
-        _H_FAULT.observe_since(t0)
+        with telemetry.span("repro.residency.fault_in"):
+            res = self._res
+            lens = self._disk_len[blocks].copy()
+            offs_old = self._disk_off[blocks].copy()
+            try:
+                payloads = res.disk.read_many_checked(offs_old, 2 * lens)
+            except ExtentCorruptionError as e:
+                # No state was mutated: surface the affected row ids so a
+                # durability layer can rebuild them from the WAL and retry.
+                bad = blocks[np.asarray(e.indices, dtype=np.int64)]
+                res.quarantined += len(e.indices)
+                raise SpillCorruptionError(self._block2row[bad].tolist()) from e
+            total = int(lens.sum())
+            buf = np.empty(total, dtype=np.uint16)
+            pos = 0
+            for j in range(blocks.size):
+                ln = int(lens[j])
+                buf[pos:pos + ln] = np.frombuffer(payloads[j], dtype=np.uint16)
+                pos += ln
+            n = int(blocks.size)
+            base = self.used
+            self._append_codes(buf)
+            self._grow_index(n)
+            first = self.n_blocks
+            self._offsets[first + 1:first + 1 + n] = base + np.cumsum(lens)
+            self._fast[first:first + n] = self._fast[blocks]
+            self._plan_ver[first:first + n] = self._plan_ver[blocks]
+            self._zone_union(first, blocks)
+            rows = self._block2row[blocks]
+            self._init_new_blocks(first, n, rows)
+            self.n_blocks += n
+            self.block_rows.extend([1] * n)
+            self._row2block[rows] = np.arange(first, first + n)
+            # the old slots are orphans now; their disk extents are freed
+            self._block2row[blocks] = -1
+            self._resident[blocks] = True
+            self._disk_off[blocks] = -1
+            self._disk_len[blocks] = 0
+            for o, ln in zip(offs_old.tolist(), lens.tolist()):
+                res.disk.free(o, framed_len(2 * ln))
+            self._spilled_codes -= total
+            res.faults += n
+            res.fault_batches += 1
+            _C_FAULT_BLOCKS.add(n)
         self.sanitize_boundary("fault_in")
 
     def _maybe_compact_disk(self) -> None:
@@ -1382,57 +1371,56 @@ class CompressedTable:
         runs carrying their residency tags (disk extent, fast flag, plan
         version) — compaction never forces a fault-in.  Returns the number
         of bytes reclaimed."""
-        t0 = telemetry.clock()
-        self._require_mutable("rewrite")
-        self.flush()
-        reclaimed = self.dead_bytes
-        nrows = self._rows_stored
-        live_rows = np.nonzero(self._row2block[:nrows] >= 0)[0]
-        blks = self._row2block[live_rows]
-        starts = self._offsets[blks]
-        lens = self._offsets[blks + 1] - starts
-        res = self._res
-        if res is not None:
-            res_mask = self._resident[blks]
-            lens = np.where(res_mask, lens, 0)  # spilled: no memory run
-        total = int(lens.sum())
-        new_off = np.zeros(live_rows.size + 1, dtype=np.int64)
-        np.cumsum(lens, out=new_off[1:])
-        gather = np.repeat(starts - new_off[:-1], lens) + np.arange(total)
-        arena = np.zeros(max(total, 1024), dtype=np.uint16)
-        arena[:total] = self.arena[gather]
-        nb = live_rows.size
-        offs = np.zeros(max(nb + 1, 1024), dtype=np.int64)
-        offs[:nb + 1] = new_off
-        fast = np.zeros(offs.size - 1, dtype=bool)
-        fast[:nb] = self._fast[blks]
-        ver = np.zeros(offs.size - 1, dtype=np.uint16)
-        ver[:nb] = self._plan_ver[blks]  # tags survive compaction
-        if res is not None:
-            resident = np.ones(offs.size - 1, dtype=bool)
-            resident[:nb] = res_mask
-            doff = np.full(offs.size - 1, -1, dtype=np.int64)
-            doff[:nb] = np.where(res_mask, -1, self._disk_off[blks])
-            dlen = np.zeros(offs.size - 1, dtype=np.int64)
-            dlen[:nb] = np.where(res_mask, 0, self._disk_len[blks])
-            ref = np.zeros(offs.size - 1, dtype=np.uint8)
-            ref[:nb] = self._ref[blks]
-            b2r = np.full(offs.size - 1, -1, dtype=np.int64)
-            b2r[:nb] = live_rows
-            self._resident, self._disk_off, self._disk_len = resident, doff, dlen
-            self._ref, self._block2row = ref, b2r
-            # the clock hand's position is meaningless after renumbering
-            res.hand = 0
-        self.arena, self.used = arena, total
-        self._offsets, self._fast, self.n_blocks = offs, fast, nb
-        self._plan_ver = ver
-        self._zone_rebuild(blks, nb)
-        self.block_rows = [1] * nb
-        self._row2block[:nrows] = -1
-        self._row2block[live_rows] = np.arange(nb)
-        self._dead_codes = 0
-        self.rewrites += 1
-        _H_REWRITE.observe_since(t0)
+        with telemetry.span("repro.store.rewrite"):
+            self._require_mutable("rewrite")
+            self.flush()
+            reclaimed = self.dead_bytes
+            nrows = self._rows_stored
+            live_rows = np.nonzero(self._row2block[:nrows] >= 0)[0]
+            blks = self._row2block[live_rows]
+            starts = self._offsets[blks]
+            lens = self._offsets[blks + 1] - starts
+            res = self._res
+            if res is not None:
+                res_mask = self._resident[blks]
+                lens = np.where(res_mask, lens, 0)  # spilled: no memory run
+            total = int(lens.sum())
+            new_off = np.zeros(live_rows.size + 1, dtype=np.int64)
+            np.cumsum(lens, out=new_off[1:])
+            gather = np.repeat(starts - new_off[:-1], lens) + np.arange(total)
+            arena = np.zeros(max(total, 1024), dtype=np.uint16)
+            arena[:total] = self.arena[gather]
+            nb = live_rows.size
+            offs = np.zeros(max(nb + 1, 1024), dtype=np.int64)
+            offs[:nb + 1] = new_off
+            fast = np.zeros(offs.size - 1, dtype=bool)
+            fast[:nb] = self._fast[blks]
+            ver = np.zeros(offs.size - 1, dtype=np.uint16)
+            ver[:nb] = self._plan_ver[blks]  # tags survive compaction
+            if res is not None:
+                resident = np.ones(offs.size - 1, dtype=bool)
+                resident[:nb] = res_mask
+                doff = np.full(offs.size - 1, -1, dtype=np.int64)
+                doff[:nb] = np.where(res_mask, -1, self._disk_off[blks])
+                dlen = np.zeros(offs.size - 1, dtype=np.int64)
+                dlen[:nb] = np.where(res_mask, 0, self._disk_len[blks])
+                ref = np.zeros(offs.size - 1, dtype=np.uint8)
+                ref[:nb] = self._ref[blks]
+                b2r = np.full(offs.size - 1, -1, dtype=np.int64)
+                b2r[:nb] = live_rows
+                self._resident, self._disk_off, self._disk_len = resident, doff, dlen
+                self._ref, self._block2row = ref, b2r
+                # the clock hand's position is meaningless after renumbering
+                res.hand = 0
+            self.arena, self.used = arena, total
+            self._offsets, self._fast, self.n_blocks = offs, fast, nb
+            self._plan_ver = ver
+            self._zone_rebuild(blks, nb)
+            self.block_rows = [1] * nb
+            self._row2block[:nrows] = -1
+            self._row2block[live_rows] = np.arange(nb)
+            self._dead_codes = 0
+            self.rewrites += 1
         return reclaimed
 
     # -- durability (DESIGN.md §7) ---------------------------------------

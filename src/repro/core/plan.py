@@ -53,14 +53,14 @@ from .vectorized import CondSlot
 
 MAX_COND_KEYS = 1 << 16  # cap on enumerated parent-chain combinations
 
-# Hot-path metric handles (DESIGN.md §9): encode/decode are leaf phases
-# of the wall-time breakdown, pallas_pack is a jit-compile event.
-_H_ENCODE = telemetry.histogram("repro.core.encode")
-_H_ENCODE_SCALAR = telemetry.histogram("repro.core.encode.scalar")
-_H_DECODE = telemetry.histogram("repro.core.decode")
+# Hot-path metric handles (DESIGN.md §9).  The ``repro.core.encode*`` /
+# ``repro.core.decode*`` spans are leaf phases of the wall-time breakdown
+# and pallas_pack is a jit-compile event; the ``repro.decode.*`` spans
+# split a decode into its stages and belong to no phase.
 _C_ENCODE_ROWS = telemetry.counter("repro.core.encode.rows")
 _C_DECODE_ROWS = telemetry.counter("repro.core.decode.rows")
-_H_PALLAS_PACK = telemetry.histogram("repro.plan.pallas_pack")
+_C_KERNEL_ROWS = telemetry.counter("repro.decode.kernel_rows")
+_C_KERNEL_ROWS_PADDED = telemetry.counter("repro.decode.kernel_rows_padded")
 _C_PALLAS_PACK = telemetry.counter("repro.plan.pallas_pack.events")
 
 
@@ -678,34 +678,32 @@ class TablePlan:
     def encode_rows(self, rows: Sequence[Dict[str, Any]]
                     ) -> Tuple[np.ndarray, np.ndarray]:
         """Rows -> (syms int64[N, S], conforming bool[N])."""
-        t0 = telemetry.clock()
-        n = len(rows)
-        self._note_rows(n)
-        cols = {name: [r[name] for r in rows] for name in self.order}
-        syms = np.zeros((n, self.S), np.int64)
-        ok = np.ones(n, bool)
-        for name, cp, off in self.lowerings:
-            try:
-                s_col, o = cp.encode(cols[name], cols)
-            except Exception:
-                self._charge(name, n)
-                ok[:] = False
-                continue
-            syms[:, off:off + cp.n_slots] = s_col
-            misses = int(n - np.count_nonzero(o))
-            if misses:
-                self._charge(name, misses)
-            ok &= o
-        _H_ENCODE_SCALAR.observe_since(t0)
+        with telemetry.span("repro.core.encode.scalar"):
+            n = len(rows)
+            self._note_rows(n)
+            cols = {name: [r[name] for r in rows] for name in self.order}
+            syms = np.zeros((n, self.S), np.int64)
+            ok = np.ones(n, bool)
+            for name, cp, off in self.lowerings:
+                try:
+                    s_col, o = cp.encode(cols[name], cols)
+                except Exception:
+                    self._charge(name, n)
+                    ok[:] = False
+                    continue
+                syms[:, off:off + cp.n_slots] = s_col
+                misses = int(n - np.count_nonzero(o))
+                if misses:
+                    self._charge(name, misses)
+                ok &= o
         return syms, ok
 
     def encode_batch(self, syms: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Symbols -> CSR ``(codes uint16, offsets int64[N+1])``."""
-        t0 = telemetry.clock()
-        codes, offsets = vectorized.encode_batch(syms, self.coders, self.lam)
-        codes = checked_astype(codes, np.uint16, where="encode_batch codes")
-        _C_ENCODE_ROWS.add(syms.shape[0])
-        _H_ENCODE.observe_since(t0)
+        with telemetry.span("repro.core.encode"):
+            codes, offsets = vectorized.encode_batch(syms, self.coders, self.lam)
+            codes = checked_astype(codes, np.uint16, where="encode_batch codes")
+            _C_ENCODE_ROWS.add(syms.shape[0])
         return codes, offsets
 
     def row_conforms(self, row: Dict[str, Any]) -> bool:
@@ -744,13 +742,13 @@ class TablePlan:
         backend: str = "numpy",
     ) -> np.ndarray:
         """Random-access decode of selected tuples -> syms int64[R, S]."""
-        t0 = telemetry.clock()
-        if backend == "pallas":
-            out = self._decode_select_pallas(codes, offsets, rows)
-        else:
-            out = vectorized.decode_select(codes, offsets, self.coders, rows, self.lam)
-        _C_DECODE_ROWS.add(int(np.size(rows)))
-        _H_DECODE.observe_since(t0)
+        with telemetry.span("repro.core.decode"):
+            if backend == "pallas":
+                out = self._decode_select_pallas(codes, offsets, rows)
+            else:
+                out = vectorized.decode_select(codes, offsets, self.coders, rows,
+                                               self.lam)
+            _C_DECODE_ROWS.add(int(np.size(rows)))
         return out
 
     def _decode_select_pallas(
@@ -763,35 +761,42 @@ class TablePlan:
         rows = np.asarray(rows, np.int64)
         if rows.size == 0:
             return np.zeros((0, self.S), np.int64)
-        # Pad the batch to a pow2 bucket so jax compiles one kernel per
-        # bucket instead of one per distinct batch size.  The floor is the
-        # kernel's row tile: every batch up to BLOCK_T rows shares one
-        # compiled program (the prepared-op cache keys on finer buckets,
-        # DESIGN.md §11).
-        n = rows.size
-        padded = max(BLOCK_T, 1 << (n - 1).bit_length())
-        if padded != n:
-            rows = np.concatenate([rows, np.full(padded - n, rows[-1], np.int64)])
-        starts = offsets[rows]
-        lens = offsets[rows + 1] - starts
-        cols = np.arange(self.S)[None, :]
-        idx = starts[:, None] + np.minimum(cols, np.maximum(lens[:, None] - 1, 0))
-        idx = np.minimum(idx, max(codes.size - 1, 0))
-        dense = np.where(cols < lens[:, None], np.asarray(codes)[idx], 0).astype(
-            np.int32
-        )
-        tables, m_bits = self.pallas_tables()
-        out = delayed_decode(jnp.asarray(dense), tables, m_bits)
-        return np.asarray(out).astype(np.int64)[:n]
+        # Prepare: everything before the result is awaited (gather, pad,
+        # host -> device copy, kernel dispatch).
+        with telemetry.span("repro.decode.prepare"):
+            # Pad the batch to a pow2 bucket so jax compiles one kernel per
+            # bucket instead of one per distinct batch size.  The floor is
+            # the kernel's row tile: every batch up to BLOCK_T rows shares
+            # one compiled program (the prepared-op cache keys on finer
+            # buckets, DESIGN.md §11).
+            n = rows.size
+            padded = max(BLOCK_T, 1 << (n - 1).bit_length())
+            if padded != n:
+                rows = np.concatenate([rows, np.full(padded - n, rows[-1], np.int64)])
+            starts = offsets[rows]
+            lens = offsets[rows + 1] - starts
+            cols = np.arange(self.S)[None, :]
+            idx = starts[:, None] + np.minimum(cols, np.maximum(lens[:, None] - 1, 0))
+            idx = np.minimum(idx, max(codes.size - 1, 0))
+            dense = np.where(cols < lens[:, None], np.asarray(codes)[idx], 0).astype(
+                np.int32
+            )
+            tables, m_bits = self.pallas_tables()
+            out = delayed_decode(jnp.asarray(dense), tables, m_bits)
+        # Wait: the device's time left after dispatch plus the copy back.
+        with telemetry.span("repro.decode.wait"):
+            syms = np.asarray(out).astype(np.int64)[:n]
+        _C_KERNEL_ROWS.add(n)
+        _C_KERNEL_ROWS_PADDED.add(padded)
+        return syms
 
     def pallas_tables(self) -> Tuple[Any, int]:
         """Lazy ``(tables f32[R, 7 * 128], m_bits)`` in the kernel's layout."""
         if self._tables is None:
-            t0 = telemetry.clock()
-            from repro.kernels.ops import pack_slot_tables
-            self._tables, self._m_bits = pack_slot_tables(self.coders)
-            _C_PALLAS_PACK.inc()
-            _H_PALLAS_PACK.observe_since(t0)
+            with telemetry.span("repro.plan.pallas_pack"):
+                from repro.kernels.ops import pack_slot_tables
+                self._tables, self._m_bits = pack_slot_tables(self.coders)
+                _C_PALLAS_PACK.inc()
         return self._tables, self._m_bits
 
     def decode_syms_to_rows(
@@ -804,6 +809,12 @@ class TablePlan:
         decode needs for context) are reconstructed, and the returned
         dicts hold exactly the requested columns.
         """
+        with telemetry.span("repro.decode.materialize"):
+            return self._syms_to_rows(syms, columns)
+
+    def _syms_to_rows(
+        self, syms: np.ndarray, columns: Optional[Sequence[str]]
+    ) -> List[Dict[str, Any]]:
         ctx: Dict[str, Any] = {}
         need: Optional[set] = None
         if columns is not None:

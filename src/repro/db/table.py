@@ -254,32 +254,31 @@ class Table:
         self, rows: Sequence[Dict[str, Any]], keys: Sequence[Key], shards: Any
     ) -> List[Key]:
         """Apply a routed insert batch (keys/shards from the prepared op)."""
-        t0 = telemetry.clock()
-        if not self._shards:
-            self._build_shards(rows)
-        batch_seen: set = set()
-        per_shard: List[List[Dict[str, Any]]] = [[] for _ in self._shards]
-        per_shard_keys: List[List[Key]] = [[] for _ in self._shards]
-        for r, k, s in zip(rows, keys, shards):
-            self.schema.validate_row(r)
-            if k in self._dir or k in batch_seen:
-                raise ValueError(
-                    f"table {self.name!r}: duplicate insert of key {k!r}"
-                )
-            batch_seen.add(k)
-            per_shard[s].append(r)
-            per_shard_keys[s].append(k)
-        self._log("insert", rows)
-        for s, (grp, gkeys) in enumerate(zip(per_shard, per_shard_keys)):
-            if not grp:
-                continue
-            _C_SHARD_CALLS.inc()
-            ids = self._shards[s].insert_many(grp)
-            for i, k in zip(ids, gkeys):
-                self._dir[k] = (s, int(i))
-        self._note_ops(len(rows))
-        _C_INSERT_ROWS.add(len(rows))
-        telemetry.record("repro.db.insert_many", t0)
+        with telemetry.span("repro.db.insert_many"):
+            if not self._shards:
+                self._build_shards(rows)
+            batch_seen: set = set()
+            per_shard: List[List[Dict[str, Any]]] = [[] for _ in self._shards]
+            per_shard_keys: List[List[Key]] = [[] for _ in self._shards]
+            for r, k, s in zip(rows, keys, shards):
+                self.schema.validate_row(r)
+                if k in self._dir or k in batch_seen:
+                    raise ValueError(
+                        f"table {self.name!r}: duplicate insert of key {k!r}"
+                    )
+                batch_seen.add(k)
+                per_shard[s].append(r)
+                per_shard_keys[s].append(k)
+            self._log("insert", rows)
+            for s, (grp, gkeys) in enumerate(zip(per_shard, per_shard_keys)):
+                if not grp:
+                    continue
+                _C_SHARD_CALLS.inc()
+                ids = self._shards[s].insert_many(grp)
+                for i, k in zip(ids, gkeys):
+                    self._dir[k] = (s, int(i))
+            self._note_ops(len(rows))
+            _C_INSERT_ROWS.add(len(rows))
         return list(keys)
 
     def _exec_get(
@@ -288,78 +287,75 @@ class Table:
         out: List[Optional[Dict[str, Any]]] = [None] * len(keys)
         if not self._shards:
             return out
-        t0 = telemetry.clock()
-        per_shard_pos: List[List[int]] = [[] for _ in self._shards]
-        per_shard_ids: List[List[int]] = [[] for _ in self._shards]
-        for pos, k in enumerate(keys):
-            slot = self._dir.get(k)
-            if slot is None:
-                continue
-            s, i = slot
-            per_shard_pos[s].append(pos)
-            per_shard_ids[s].append(i)
-        for s, (poss, ids) in enumerate(zip(per_shard_pos, per_shard_ids)):
-            if not ids:
-                continue
-            _C_SHARD_CALLS.inc()
-            got = self._shards[s].get_many(ids, backend=backend)
-            for pos, row in zip(poss, got):
-                out[pos] = row
-        _C_GET_ROWS.add(len(keys))
-        telemetry.record("repro.db.get_many", t0)
+        with telemetry.span("repro.db.get_many"):
+            per_shard_pos: List[List[int]] = [[] for _ in self._shards]
+            per_shard_ids: List[List[int]] = [[] for _ in self._shards]
+            for pos, k in enumerate(keys):
+                slot = self._dir.get(k)
+                if slot is None:
+                    continue
+                s, i = slot
+                per_shard_pos[s].append(pos)
+                per_shard_ids[s].append(i)
+            for s, (poss, ids) in enumerate(zip(per_shard_pos, per_shard_ids)):
+                if not ids:
+                    continue
+                _C_SHARD_CALLS.inc()
+                got = self._shards[s].get_many(ids, backend=backend)
+                for pos, row in zip(poss, got):
+                    out[pos] = row
+            _C_GET_ROWS.add(len(keys))
         return out
 
     def _exec_update(
         self, keys: Sequence[Key], rows: Sequence[Dict[str, Any]]
     ) -> None:
-        t0 = telemetry.clock()
-        merged: Dict[Key, Dict[str, Any]] = {}
-        for k, r in zip(keys, rows):
-            self.schema.validate_row(r)  # fail here, not in a later merge
-            if self.schema.key_of(r) != k:
-                raise ValueError(
-                    f"table {self.name!r}: update changes primary key "
-                    f"{k!r} -> {self.schema.key_of(r)!r}"
-                )
-            merged[k] = r
-        per_shard_ids: List[List[int]] = [[] for _ in self._shards]
-        per_shard_rows: List[List[Dict[str, Any]]] = [[] for _ in self._shards]
-        for k, r in merged.items():
-            s, i = self._route(k)
-            per_shard_ids[s].append(i)
-            per_shard_rows[s].append(r)
-        self._log("update", list(merged.values()))
-        for s, (ids, grp) in enumerate(zip(per_shard_ids, per_shard_rows)):
-            if ids:
-                _C_SHARD_CALLS.inc()
-                self._shards[s].update_many(ids, grp)
-        self._note_ops(len(merged))
-        _C_UPDATE_ROWS.add(len(merged))
-        telemetry.record("repro.db.update_many", t0)
+        with telemetry.span("repro.db.update_many"):
+            merged: Dict[Key, Dict[str, Any]] = {}
+            for k, r in zip(keys, rows):
+                self.schema.validate_row(r)  # fail here, not in a later merge
+                if self.schema.key_of(r) != k:
+                    raise ValueError(
+                        f"table {self.name!r}: update changes primary key "
+                        f"{k!r} -> {self.schema.key_of(r)!r}"
+                    )
+                merged[k] = r
+            per_shard_ids: List[List[int]] = [[] for _ in self._shards]
+            per_shard_rows: List[List[Dict[str, Any]]] = [[] for _ in self._shards]
+            for k, r in merged.items():
+                s, i = self._route(k)
+                per_shard_ids[s].append(i)
+                per_shard_rows[s].append(r)
+            self._log("update", list(merged.values()))
+            for s, (ids, grp) in enumerate(zip(per_shard_ids, per_shard_rows)):
+                if ids:
+                    _C_SHARD_CALLS.inc()
+                    self._shards[s].update_many(ids, grp)
+            self._note_ops(len(merged))
+            _C_UPDATE_ROWS.add(len(merged))
 
     def _exec_delete(self, keys: Sequence[Key]) -> int:
-        t0 = telemetry.clock()
-        per_shard_ids: List[List[int]] = [[] for _ in self._shards]
-        dropped: List[Key] = []
-        for k in dict.fromkeys(keys):  # dedup, keep order
-            slot = self._dir.get(k)
-            if slot is None:
-                continue
-            s, i = slot
-            per_shard_ids[s].append(i)
-            dropped.append(k)
-        if dropped:
-            self._log("delete", dropped)
-        n = 0
-        for s, ids in enumerate(per_shard_ids):
-            if ids:
-                _C_SHARD_CALLS.inc()
-                n += self._shards[s].delete_many(ids)
-        for k in dropped:
-            del self._dir[k]
-        self._note_ops(len(dropped))
-        _C_DELETE_ROWS.add(len(dropped))
-        telemetry.record("repro.db.delete_many", t0)
+        with telemetry.span("repro.db.delete_many"):
+            per_shard_ids: List[List[int]] = [[] for _ in self._shards]
+            dropped: List[Key] = []
+            for k in dict.fromkeys(keys):  # dedup, keep order
+                slot = self._dir.get(k)
+                if slot is None:
+                    continue
+                s, i = slot
+                per_shard_ids[s].append(i)
+                dropped.append(k)
+            if dropped:
+                self._log("delete", dropped)
+            n = 0
+            for s, ids in enumerate(per_shard_ids):
+                if ids:
+                    _C_SHARD_CALLS.inc()
+                    n += self._shards[s].delete_many(ids)
+            for k in dropped:
+                del self._dir[k]
+            self._note_ops(len(dropped))
+            _C_DELETE_ROWS.add(len(dropped))
         return n
 
     # -- scalar wrappers -------------------------------------------------
@@ -496,6 +492,19 @@ class Table:
         ``with_stats=True`` returns ``(groups, merged ScanStats)`` — the
         same stats shape :meth:`scan_where` reports (DESIGN.md §8).
         """
+        with telemetry.span("repro.db.aggregate"):
+            return self._aggregate(predicates, group_by, aggs, pushdown, backend,
+                                   with_stats)
+
+    def _aggregate(
+        self,
+        predicates: Sequence[Any],
+        group_by: Sequence[str],
+        aggs: Optional[Dict[str, Tuple[str, Optional[str]]]],
+        pushdown: bool,
+        backend: Optional[str],
+        with_stats: bool,
+    ) -> Any:
         from repro.scan import ScanStats
 
         aggs = dict(aggs or {"count": ("count", None)})

@@ -35,8 +35,6 @@ from repro.core.arena import OS_IO
 RECORD_MAGIC = 0x57414C31  # "WAL1"
 RECORD_HEADER = struct.Struct("<III")
 
-_H_APPEND = telemetry.histogram("repro.wal.append")
-_H_FSYNC = telemetry.histogram("repro.wal.fsync")
 _C_RECORDS = telemetry.counter("repro.wal.records")
 _C_BYTES = telemetry.counter("repro.wal.bytes")
 _C_FSYNCS = telemetry.counter("repro.wal.fsyncs")
@@ -104,15 +102,13 @@ class WriteAheadLog:
         buf = b"".join(self._pending)
         self.io.point("wal.before_flush")
         try:
-            t0 = telemetry.clock()
-            self.io.pwrite(self._fd, buf, self._tail)
-            _H_APPEND.observe_since(t0)
+            with telemetry.span("repro.wal.append"):
+                self.io.pwrite(self._fd, buf, self._tail)
             self._flushes += 1
             if self.fsync_every and self._flushes % self.fsync_every == 0:
                 self.io.point("wal.before_fsync")
-                t0 = telemetry.clock()
-                self.io.fsync(self._fd)
-                _H_FSYNC.observe_since(t0)
+                with telemetry.span("repro.wal.fsync"):
+                    self.io.fsync(self._fd)
                 _C_FSYNCS.inc()
         except OSError:
             self.poisoned = True

@@ -42,7 +42,6 @@ _C_HIT = telemetry.counter("repro.exec.plan.hit")
 _C_MISS = telemetry.counter("repro.exec.plan.miss")
 _C_REPLAY = telemetry.counter("repro.exec.replay")
 _C_REPLAY_ROWS = telemetry.counter("repro.exec.replay.rows")
-_H_LOWER = telemetry.histogram("repro.exec.lower")
 
 VERBS = ("insert", "get", "update", "delete")
 
@@ -107,14 +106,13 @@ class PreparedOp:
             codec = getattr(shard, "codec", None)
             if codec is not None:
                 plans.append(codec.compile())
-        t0 = telemetry.clock()
-        if backend == "pallas":
-            for plan in plans:
-                if plan is not None and plan.pallas_ok:
-                    plan.pallas_tables()
-        low = _Lowered(epoch, len(table.schema.primary_key), table.n_shards)
-        self._cache[slot] = low
-        _H_LOWER.observe_since(t0)
+        with telemetry.span("repro.exec.lower"):
+            if backend == "pallas":
+                for plan in plans:
+                    if plan is not None and plan.pallas_ok:
+                        plan.pallas_tables()
+            low = _Lowered(epoch, len(table.schema.primary_key), table.n_shards)
+            self._cache[slot] = low
         return low
 
     def invalidate(self) -> None:

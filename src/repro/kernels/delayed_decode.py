@@ -47,7 +47,6 @@ SUBLANES = 8
 # first-call wall time is attributed to the jit_compile phase (it is
 # compile-dominated), cache hits are counted separately.
 _SEEN_SIGS: Set[Tuple] = set()
-_H_JIT = telemetry.histogram("repro.plan.compile.pallas_jit")
 _C_JIT_MISS = telemetry.counter("repro.plan.cache.pallas_miss")
 _C_JIT_HIT = telemetry.counter("repro.plan.cache.pallas_hit")
 
@@ -178,10 +177,8 @@ def delayed_decode(
         return _delayed_decode_jit(codes_dense, tables, m_bits, interpret)
     _SEEN_SIGS.add(sig)
     _C_JIT_MISS.inc()
-    t0 = telemetry.clock()
-    out = _delayed_decode_jit(codes_dense, tables, m_bits, interpret)
-    _H_JIT.observe_since(t0)
-    return out
+    with telemetry.span("repro.plan.compile.pallas_jit"):
+        return _delayed_decode_jit(codes_dense, tables, m_bits, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("m_bits", "interpret"))

@@ -53,11 +53,8 @@ TOMBSTONE_BYTES = 8
 # Telemetry handles (DESIGN.md §9): delta-merge and the byte-store's
 # cold-tier spill/fault-in, which shares phase prefixes with the
 # CompressedTable block paths.
-_H_MERGE = telemetry.histogram("repro.store.merge")
 _C_MERGES = telemetry.counter("repro.store.merge.events")
 _C_OVERLAY_HITS = telemetry.counter("repro.store.overlay.hits")
-_H_ROW_FAULT = telemetry.histogram("repro.residency.fault_in.rows")
-_H_ROW_SPILL = telemetry.histogram("repro.residency.spill.rows")
 _C_ROW_FAULTS = telemetry.counter("repro.residency.fault_in.rows.count")
 _C_ROW_SPILLS = telemetry.counter("repro.residency.spill.rows.count")
 
@@ -318,44 +315,43 @@ class _BytesRowStore(RowStore):
             else:
                 out[j] = p
         if cold:
-            t0 = telemetry.clock()
-            res = self._res
-            ids = sorted(set(cold))
-            for _attempt in range(3):
-                extents = [self._spilled[i] for i in ids]
-                try:
-                    payloads = res.disk.read_many_checked(
-                        [e[0] for e in extents], [e[1] for e in extents]
-                    )
-                    break
-                except ExtentCorruptionError as e:
-                    # Quarantine the bad extents and rebuild their rows
-                    # from the WAL (repair_fn); repaired rows come back
-                    # resident, the rest retry the checked read.
-                    bad = [ids[j] for j in e.indices]
-                    res.quarantined += len(bad)
-                    self._repair_rows(bad)
-                    ids = [i for i in ids if i in self._spilled]
-                    payloads = []
-            else:
-                raise SpillCorruptionError(ids)
-            # blitzlint: waive[BL001] -- crash-replay fault bookkeeping frees per-row extents on the cold repair path
-            for i, p in zip(ids, payloads):
-                off, ln = self._spilled.pop(i)
-                rows[i] = p
-                res.disk.free(off, framed_len(ln))
-                self._resident_bytes += ln
-                self._spilled_payload -= ln
-                self._ref[i] = 1
-            if ids:
-                res.faults += len(ids)
-                res.fault_batches += 1
-                _C_ROW_FAULTS.add(len(ids))
-            for j, i in enumerate(indices):
-                if out[j] is None and i not in dels:
-                    out[j] = rows[i]
-            self._enforce_budget()
-            _H_ROW_FAULT.observe_since(t0)
+            with telemetry.span("repro.residency.fault_in.rows"):
+                res = self._res
+                ids = sorted(set(cold))
+                for _attempt in range(3):
+                    extents = [self._spilled[i] for i in ids]
+                    try:
+                        payloads = res.disk.read_many_checked(
+                            [e[0] for e in extents], [e[1] for e in extents]
+                        )
+                        break
+                    except ExtentCorruptionError as e:
+                        # Quarantine the bad extents and rebuild their rows
+                        # from the WAL (repair_fn); repaired rows come back
+                        # resident, the rest retry the checked read.
+                        bad = [ids[j] for j in e.indices]
+                        res.quarantined += len(bad)
+                        self._repair_rows(bad)
+                        ids = [i for i in ids if i in self._spilled]
+                        payloads = []
+                else:
+                    raise SpillCorruptionError(ids)
+                # blitzlint: waive[BL001] -- crash-replay fault bookkeeping frees per-row extents on the cold repair path
+                for i, p in zip(ids, payloads):
+                    off, ln = self._spilled.pop(i)
+                    rows[i] = p
+                    res.disk.free(off, framed_len(ln))
+                    self._resident_bytes += ln
+                    self._spilled_payload -= ln
+                    self._ref[i] = 1
+                if ids:
+                    res.faults += len(ids)
+                    res.fault_batches += 1
+                    _C_ROW_FAULTS.add(len(ids))
+                for j, i in enumerate(indices):
+                    if out[j] is None and i not in dels:
+                        out[j] = rows[i]
+                self._enforce_budget()
         if self._res is not None:
             for i in indices:
                 if i not in dels:
@@ -405,20 +401,19 @@ class _BytesRowStore(RowStore):
     def _spill_rows(self, ids: List[int]) -> None:
         """One coalesced segment write (CRC32-framed extents) for the
         whole victim set."""
-        t0 = telemetry.clock()
-        res = self._res
-        payloads = [self.rows[i] for i in ids]
-        offs = res.disk.write_many(payloads)
-        # blitzlint: waive[BL001] -- per-row extent directory update after one coalesced segment write
-        for i, off, p in zip(ids, offs, payloads):
-            ln = len(p)
-            self._spilled[i] = (off, ln)
-            self.rows[i] = None
-            self._resident_bytes -= ln
-            self._spilled_payload += ln
-        res.spills += len(ids)
-        _C_ROW_SPILLS.add(len(ids))
-        _H_ROW_SPILL.observe_since(t0)
+        with telemetry.span("repro.residency.spill.rows"):
+            res = self._res
+            payloads = [self.rows[i] for i in ids]
+            offs = res.disk.write_many(payloads)
+            # blitzlint: waive[BL001] -- per-row extent directory update after one coalesced segment write
+            for i, off, p in zip(ids, offs, payloads):
+                ln = len(p)
+                self._spilled[i] = (off, ln)
+                self.rows[i] = None
+                self._resident_bytes -= ln
+                self._spilled_payload += ln
+            res.spills += len(ids)
+            _C_ROW_SPILLS.add(len(ids))
 
     def _repair_rows(self, ids: List[int]) -> None:
         """Rebuild corrupt spilled rows from the WAL via ``repair_fn``.
@@ -883,26 +878,25 @@ class BlitzStore(RowStore):
         """
         if self.block_tuples != 1:
             raise ValueError("merge requires block_tuples == 1")
-        t0 = telemetry.clock()
-        if sanitize.ENABLED:
-            sanitize.check_overlay(
-                self._overlay, self._tombstones, where="BlitzStore.merge"
-            )
-        if self._tombstones:
-            self.table.delete_many(sorted(self._tombstones))
-            self._tombstones.clear()
-        if self._overlay:
-            idxs = sorted(self._overlay)
-            self.table.replace_many(idxs, [self._overlay[i] for i in idxs])
-            self._overlay.clear()
-            self._overlay_bytes = 0
-        self.merges += 1
-        _C_MERGES.inc()
-        if self.table.dead_bytes > max(
-            self.merge_min_bytes, self.rewrite_frac * 2 * self.table.used
-        ):
-            self.table.rewrite()
-        _H_MERGE.observe_since(t0)
+        with telemetry.span("repro.store.merge"):
+            if sanitize.ENABLED:
+                sanitize.check_overlay(
+                    self._overlay, self._tombstones, where="BlitzStore.merge"
+                )
+            if self._tombstones:
+                self.table.delete_many(sorted(self._tombstones))
+                self._tombstones.clear()
+            if self._overlay:
+                idxs = sorted(self._overlay)
+                self.table.replace_many(idxs, [self._overlay[i] for i in idxs])
+                self._overlay.clear()
+                self._overlay_bytes = 0
+            self.merges += 1
+            _C_MERGES.inc()
+            if self.table.dead_bytes > max(
+                self.merge_min_bytes, self.rewrite_frac * 2 * self.table.used
+            ):
+                self.table.rewrite()
         return self.stats()
 
     # -- durability (DESIGN.md §7) ---------------------------------------

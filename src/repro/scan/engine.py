@@ -356,7 +356,16 @@ def scan_table(
     filtering in value space.  Read-only: never flushes pending rows,
     faults in cold blocks, or advances the clock.
     """
-    t0 = telemetry.clock()
+    with telemetry.span("repro.scan.scan_table"):
+        return _scan_table(table, predicates, columns, backend)
+
+
+def _scan_table(
+    table,
+    predicates: Sequence[Predicate],
+    columns: Optional[Sequence[str]],
+    backend: Optional[str],
+) -> ScanResult:
     table.sanitize_boundary("scan_table")
     preds = list(predicates)
     stats = ScanStats()
@@ -391,7 +400,6 @@ def scan_table(
         for i, r in enumerate(table._pending):
             _value_filtered(table._rows_stored + i, r)
         stats.rows_matched = len(hits)
-        telemetry.record("repro.scan.scan_table", t0)
         return ScanResult([h[0] for h in hits], [h[1] for h in hits], stats)
 
     nrows = table._rows_stored
@@ -483,5 +491,4 @@ def scan_table(
 
     hits.sort(key=lambda h: h[0])
     stats.rows_matched = len(hits)
-    telemetry.record("repro.scan.scan_table", t0)
     return ScanResult([h[0] for h in hits], [h[1] for h in hits], stats)

@@ -1,15 +1,15 @@
 """Unified telemetry layer (DESIGN.md §9): one engine-wide metrics
-registry, nestable trace spans over a ring-buffer event log, and
-snapshot exporters.
+registry, timed spans that also land in a recording JAX profiler trace,
+and snapshot exporters.
 
 Quickstart::
 
     from repro import telemetry
 
-    H = telemetry.histogram("repro.db.get_many")   # cached handle
-    t0 = telemetry.clock()                         # 0 when disabled
-    ...hot path...
-    H.observe_since(t0)                            # no-op on 0
+    with telemetry.span("repro.db.get_many"):      # histogram + trace
+        ...hot path...
+    ROWS = telemetry.counter("repro.db.get_many.rows")  # cached handle
+    ROWS.add(n)
 
     telemetry.snapshot()        # counters + histogram percentiles
     telemetry.to_prometheus()   # scrape-format text
@@ -31,24 +31,14 @@ from .metrics import (
     N_BUCKETS,
     REGISTRY,
     Counter,
-    Gauge,
     Histogram,
     Registry,
     bucket_index,
     bucket_lo,
-    clock,
     enabled,
     set_enabled,
 )
-from .spans import (
-    EVENTS,
-    EventLog,
-    Span,
-    SpanEvent,
-    events_snapshot,
-    record,
-    span,
-)
+from .spans import Span, span
 
 
 def counter(name: str) -> Counter:
@@ -56,15 +46,10 @@ def counter(name: str) -> Counter:
     return REGISTRY.counter(name)
 
 
-def gauge(name: str) -> Gauge:
-    return REGISTRY.gauge(name)
-
-
 def histogram(name: str) -> Histogram:
     return REGISTRY.histogram(name)
 
 
 def reset() -> None:
-    """Zero the engine-wide registry and event ring (handles stay valid)."""
+    """Zero the engine-wide registry (handles stay valid)."""
     REGISTRY.reset()
-    EVENTS.reset()

@@ -24,6 +24,12 @@ METRICS: Tuple[str, ...] = (
     "repro.core.encode.rows",
     "repro.core.encode.scalar",
     "repro.core.encode.scalar_block",
+    # -- decode stages inside a core decode (not phase sources) ---------
+    "repro.decode.kernel_rows",
+    "repro.decode.kernel_rows_padded",
+    "repro.decode.materialize",
+    "repro.decode.prepare",
+    "repro.decode.wait",
     # -- plan compilation and kernel caches -----------------------------
     "repro.plan.cache.hit",
     "repro.plan.cache.miss",
@@ -62,6 +68,7 @@ METRICS: Tuple[str, ...] = (
     "repro.exec.replay",
     "repro.exec.replay.rows",
     # -- db engine (batched verbs; span + rows-counter pairs) -------------
+    "repro.db.aggregate",
     "repro.db.delete_many",
     "repro.db.delete_many.rows",
     "repro.db.get_many",
@@ -83,6 +90,8 @@ METRICS: Tuple[str, ...] = (
     "repro.scan.scan_table",
     "repro.scan.spilled_reads",
     "repro.scan.versions",
+    # -- interpreter: one span per CPython collection -------------------
+    "repro.runtime.gc",
     # -- sanitizer (DESIGN.md §10: boundary-check accounting) --------------
     "repro.sanitize.checks",
     "repro.sanitize.failures",

@@ -15,7 +15,6 @@ import json
 from typing import Dict, List, Optional, Tuple
 
 from .metrics import REGISTRY, Registry, _state
-from .spans import EVENTS, EventLog, events_snapshot
 
 # phase -> histogram-name prefixes whose total time it absorbs.  Every
 # prefix is a leaf region; see the module docstring for why that makes
@@ -34,36 +33,30 @@ PHASE_SOURCES: Dict[str, Tuple[str, ...]] = {
 def snapshot(
     registry: Optional[Registry] = None,
     prefix: Optional[Tuple[str, ...] | str] = None,
-    events: bool = False,
-    log: Optional[EventLog] = None,
 ) -> Dict:
-    """JSON-friendly view of the registry: counters, gauges, histogram
+    """JSON-friendly view of the registry: counters and histogram
     summaries (count + total + p50/p95/p99/max in microseconds).
 
     ``prefix`` filters metric names — the per-subsystem ``stats()``
     sections use it so a store reports store/core/wal metrics, not the
-    whole engine.  ``events=True`` appends the tail of the span ring.
+    whole engine.
     """
     reg = registry or REGISTRY
 
     def keep(name: str) -> bool:
         return prefix is None or name.startswith(prefix)
 
-    out: Dict = {
+    return {
         "enabled": _state.enabled,
         "counters": {
             n: c.value for n, c in sorted(reg.counters().items()) if keep(n)
         },
-        "gauges": {n: g.value for n, g in sorted(reg.gauges().items()) if keep(n)},
         "histograms": {
             n: h.summary()
             for n, h in sorted(reg.histograms().items())
             if keep(n) and h.count
         },
     }
-    if events:
-        out["events"] = events_snapshot(log or EVENTS)
-    return out
 
 
 def _prom_name(name: str) -> str:
@@ -83,10 +76,6 @@ def to_prometheus(registry: Optional[Registry] = None) -> str:
         pn = _prom_name(n)
         lines.append(f"# TYPE {pn}_total counter")
         lines.append(f"{pn}_total {c.value}")
-    for n, g in sorted(reg.gauges().items()):
-        pn = _prom_name(n)
-        lines.append(f"# TYPE {pn} gauge")
-        lines.append(f"{pn} {g.value}")
     for n, h in sorted(reg.histograms().items()):
         if not h.count:
             continue

@@ -1,16 +1,14 @@
-"""Low-overhead metrics primitives: counters, gauges, log-bucketed
-latency histograms, and the registry that names them (DESIGN.md §9).
+"""Low-overhead metrics primitives: counters, log-bucketed latency
+histograms, and the registry that names them (DESIGN.md §9).
 
 Design constraints, in priority order:
 
 1. **Near-zero cost when disabled.**  Every mutating entry point checks
-   one module-level flag and returns; ``clock()`` returns 0 so the
-   paired ``observe_since(0)`` is a no-op too.  Instrumented code never
-   branches on telemetry state itself — it always calls the same
-   handles, which are cheap either way.
+   one module-level flag and returns (a span is a shared no-op object,
+   :mod:`.spans`).  Instrumented code never branches on telemetry state
+   itself — it always calls the same handles, which are cheap either way.
 2. **Cheap when enabled.**  A counter bump is one attribute add; a
-   histogram observation is one ``perf_counter_ns`` delta, one
-   ``bit_length``-style log2, and two integer adds.  Handles are
+   histogram observation is one log2 and two integer adds.  Handles are
    created once at import/module scope and cached by name, so the hot
    path never touches the registry dict.
 3. **Lossless merge.**  Histograms store integer bucket counts plus
@@ -27,7 +25,6 @@ from __future__ import annotations
 
 import math
 import threading
-import time
 from typing import Dict, List, Optional
 
 # Histogram geometry: 4 buckets per octave (bucket i spans
@@ -65,15 +62,6 @@ def set_enabled(on: bool) -> bool:
     return prev
 
 
-def clock() -> int:
-    """Start-of-region timestamp: ``perf_counter_ns`` when enabled, else 0.
-
-    Pair with :meth:`Histogram.observe_since` — a 0 start makes the
-    observe a no-op, so a disabled region costs one flag check total.
-    """
-    return time.perf_counter_ns() if _state.enabled else 0
-
-
 def bucket_index(ns: float) -> int:
     """Bucket holding a duration of ``ns`` nanoseconds (clamped)."""
     if ns < 1.0:
@@ -105,23 +93,6 @@ class Counter:
 
     def reset(self) -> None:
         self.value = 0
-
-
-class Gauge:
-    """Last-write-wins instantaneous value (bytes resident, queue depth)."""
-
-    __slots__ = ("name", "value")
-
-    def __init__(self, name: str) -> None:
-        self.name = name
-        self.value = 0.0
-
-    def set(self, v: float) -> None:
-        if _state.enabled:
-            self.value = float(v)
-
-    def reset(self) -> None:
-        self.value = 0.0
 
 
 class Histogram:
@@ -156,11 +127,6 @@ class Histogram:
         self.count += 1
         self.sum_ns += int(ns)
         self.buckets[bucket_index(ns)] += 1
-
-    def observe_since(self, t0_ns: int) -> None:
-        """Record the elapsed time since a :func:`clock` start (no-op on 0)."""
-        if t0_ns:
-            self.observe(time.perf_counter_ns() - t0_ns)
 
     def percentile(self, q: float) -> float:
         """q-quantile in nanoseconds (geometric bucket midpoint); 0.0 when
@@ -222,7 +188,6 @@ class Registry:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._counters: Dict[str, Counter] = {}
-        self._gauges: Dict[str, Gauge] = {}
         self._hists: Dict[str, Histogram] = {}
 
     def counter(self, name: str) -> Counter:
@@ -231,13 +196,6 @@ class Registry:
             with self._lock:
                 c = self._counters.setdefault(name, Counter(name))
         return c
-
-    def gauge(self, name: str) -> Gauge:
-        g = self._gauges.get(name)
-        if g is None:
-            with self._lock:
-                g = self._gauges.setdefault(name, Gauge(name))
-        return g
 
     def histogram(self, name: str) -> Histogram:
         h = self._hists.get(name)
@@ -248,9 +206,6 @@ class Registry:
 
     def counters(self) -> Dict[str, Counter]:
         return dict(self._counters)
-
-    def gauges(self) -> Dict[str, Gauge]:
-        return dict(self._gauges)
 
     def histograms(self) -> Dict[str, Histogram]:
         return dict(self._hists)
@@ -267,8 +222,6 @@ class Registry:
         """Zero every metric *in place* — cached handles stay valid."""
         for c in self._counters.values():
             c.reset()
-        for g in self._gauges.values():
-            g.reset()
         for h in self._hists.values():
             h.reset()
 

@@ -1,103 +1,66 @@
-"""Nestable trace spans over a fixed-size ring-buffer event log.
+"""Timed regions: the program's one region timer, on the profiler's clock.
 
-A span times one logical region (``with span("repro.db.get_many"):``),
-feeds its duration into the same-named registry histogram, and appends
-a compact event tuple to a bounded ring buffer — the last N operations
-are always inspectable without unbounded memory growth.  Nesting is
-tracked per thread; the recorded ``depth`` reconstructs the call tree.
+A span times one logical region (``with span("repro.db.get_many"):``) and
+feeds its duration into the same-named registry histogram.  While a JAX
+profiler trace is recording, the span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so the trace's host
+plane shows the program's regions on the device trace's clock.  With no
+trace recording, that costs one flag check; with telemetry disabled, a
+span is one shared no-op object.
 
-Disabled mode returns one shared no-op span object: no allocation, no
-clock reads, no ring writes.
+The collector gets a span of its own: a ``gc.callbacks`` hook times every
+CPython collection into ``repro.runtime.gc`` (and annotates it while a
+trace records).  It does nothing while telemetry is disabled.
 """
 
 from __future__ import annotations
 
-import threading
+import gc
+import sys
 import time
-from typing import Any, Dict, List, NamedTuple, Optional
+from typing import Any, Dict, Optional
 
-from .metrics import REGISTRY, Registry, _state
+from .metrics import REGISTRY, Histogram, Registry, _state
 
-DEFAULT_EVENT_CAPACITY = 1024
-
-
-class SpanEvent(NamedTuple):
-    seq: int  # monotonically increasing across wraparound
-    name: str
-    depth: int  # nesting level at the time the span ran
-    start_ns: int  # perf_counter_ns at entry
-    dur_ns: int
+_annotation_cls: Any = None
 
 
-class EventLog:
-    """Fixed-capacity ring buffer of :class:`SpanEvent` s.
-
-    ``append`` overwrites the oldest entry once full; ``total`` keeps
-    counting, so ``total - len(self)`` is the number of dropped events.
-    """
-
-    def __init__(self, capacity: int = DEFAULT_EVENT_CAPACITY) -> None:
-        if capacity <= 0:
-            raise ValueError("EventLog capacity must be positive")
-        self.capacity = capacity
-        self._buf: List[Optional[SpanEvent]] = [None] * capacity
-        self._next = 0
-        self.total = 0
-
-    def append(self, ev: SpanEvent) -> None:
-        self._buf[self._next] = ev
-        self._next = (self._next + 1) % self.capacity
-        self.total += 1
-
-    def __len__(self) -> int:
-        return min(self.total, self.capacity)
-
-    def events(self) -> List[SpanEvent]:
-        """Retained events, oldest first (wraparound unrolled)."""
-        if self.total <= self.capacity:
-            return [e for e in self._buf[: self.total] if e is not None]
-        return [
-            e
-            for e in self._buf[self._next :] + self._buf[: self._next]
-            if e is not None
-        ]
-
-    def reset(self) -> None:
-        self._buf = [None] * self.capacity
-        self._next = 0
-        self.total = 0
-
-
-EVENTS = EventLog()
-
-_tls = threading.local()
+def _annotation() -> Any:
+    """``jax.profiler.TraceAnnotation`` while a profiler trace records,
+    else None.  JAX is never imported from here: a process that has not
+    loaded ``jax.profiler`` cannot be recording a trace."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        # getattr, not import: a collection can fire while jax imports
+        cls = _annotation_cls = getattr(sys.modules.get("jax.profiler"),
+                                        "TraceAnnotation", None)
+    return cls if cls is not None and cls.is_enabled() else None
 
 
 class Span:
-    """One active timed region; re-entrant use creates nested events."""
+    """One active timed region."""
 
-    __slots__ = ("name", "registry", "log", "_t0", "_depth")
+    __slots__ = ("hist", "_t0", "_ann")
 
-    def __init__(self, name: str, registry: Registry, log: EventLog) -> None:
-        self.name = name
-        self.registry = registry
-        self.log = log
+    def __init__(self, hist: Histogram) -> None:
+        self.hist = hist
         self._t0 = 0
-        self._depth = 0
+        self._ann: Any = None
 
     def __enter__(self) -> "Span":
-        self._depth = getattr(_tls, "depth", 0)
-        _tls.depth = self._depth + 1
+        ann = _annotation()
+        if ann is not None:
+            self._ann = ann(self.hist.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: Any) -> None:
-        dur = time.perf_counter_ns() - self._t0
-        _tls.depth = self._depth
-        self.registry.histogram(self.name).observe(dur)
-        self.log.append(
-            SpanEvent(self.log.total, self.name, self._depth, self._t0, dur)
-        )
+        self.hist.observe(time.perf_counter_ns() - self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
 
 
 class _NullSpan:
@@ -115,44 +78,29 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
-def span(
-    name: str,
-    registry: Optional[Registry] = None,
-    log: Optional[EventLog] = None,
-) -> Any:
-    """Context manager timing one region into histogram ``name`` and the
-    event ring.  Returns a shared no-op when telemetry is disabled."""
+def span(name: str, registry: Optional[Registry] = None) -> Any:
+    """Context manager timing one region into histogram ``name`` (and the
+    profiler trace, while one records).  Returns a shared no-op when
+    telemetry is disabled."""
     if not _state.enabled:
         return _NULL
-    return Span(name, registry or REGISTRY, log or EVENTS)
+    return Span((registry or REGISTRY).histogram(name))
 
 
-def record(
-    name: str,
-    t0_ns: int,
-    registry: Optional[Registry] = None,
-    log: Optional[EventLog] = None,
-) -> None:
-    """Manual span close for code that can't use ``with`` (multiple
-    returns, no reindent): pair with a :func:`repro.telemetry.clock`
-    start.  No-op when the start was taken disabled (``t0_ns == 0``)."""
-    if not t0_ns:
-        return
-    dur = time.perf_counter_ns() - t0_ns
-    (registry or REGISTRY).histogram(name).observe(dur)
-    elog = log or EVENTS
-    elog.append(SpanEvent(elog.total, name, getattr(_tls, "depth", 0), t0_ns, dur))
+class _GcSpan:
+    """``gc.callbacks`` hook: one ``repro.runtime.gc`` span per collection,
+    opened at the collector's ``start`` and closed at its ``stop``."""
+
+    def __init__(self) -> None:
+        self._open: Any = None
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            self._open = span("repro.runtime.gc")
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
 
 
-def events_snapshot(log: Optional[EventLog] = None, limit: int = 64) -> List[Dict]:
-    """Last ``limit`` retained events as JSON-friendly dicts (newest last)."""
-    evs = (log or EVENTS).events()[-limit:]
-    return [
-        {
-            "seq": e.seq,
-            "name": e.name,
-            "depth": e.depth,
-            "dur_us": round(e.dur_ns / 1e3, 3),
-        }
-        for e in evs
-    ]
+gc.callbacks.append(_GcSpan())

@@ -257,6 +257,21 @@ class TestAggregate:
             assert got[g]["lo"] == min(w["qs"])
             assert got[g]["hi"] == max(w["qs"])
 
+    def test_aggregate_span_encloses_its_scans(self):
+        from repro import telemetry
+
+        db, t = _build_table(n=300, n_shards=2, seed=14, churn=False)
+        prev = telemetry.set_enabled(True)
+        telemetry.reset()
+        try:
+            t.aggregate([Range("qty", lo=5)], group_by=("w",))
+            agg = telemetry.REGISTRY.histogram("repro.db.aggregate")
+            scans = telemetry.REGISTRY.histogram("repro.scan.scan_table")
+            assert agg.count == 1 and scans.count == 2  # one scan per shard
+            assert scans.sum_ns <= agg.sum_ns
+        finally:
+            telemetry.set_enabled(prev)
+
     def test_database_query_routes(self):
         db, t = _build_table(n=300, n_shards=1, seed=12)
         preds = [Eq("w", "w1")]

@@ -1,8 +1,9 @@
 """Telemetry layer (DESIGN.md §9): metric primitives, spans, exporters.
 
 The load-bearing invariants: histogram merge is lossless (commutative,
-associative, equal to observing the concatenated stream), the event ring
-survives wraparound with ordering intact, ScanStats keeps its attribute
+associative, equal to observing the concatenated stream), spans land in a
+recording profiler trace and cost nothing there otherwise, the decode's
+stage spans stay inside its phase, ScanStats keeps its attribute
 API while flowing deltas into shared registry counters without
 double-counting on merge, and — most importantly — disabling telemetry
 changes *nothing* about engine behaviour: an enabled and a disabled run
@@ -10,23 +11,40 @@ produce bit-identical store state.
 """
 
 import dataclasses
+import gc
+import glob
+import os
 import pickle
 import random
 
+import numpy as np
 import pytest
 
 from repro import telemetry
-from repro.core.blitzcrank import ColumnSpec
+from repro.core.blitzcrank import ColumnSpec, TableCodec
 from repro.oltp.store import BlitzStore
 from repro.scan.engine import ScanStats
 from repro.telemetry import (
     N_BUCKETS,
-    EventLog,
+    PHASE_SOURCES,
     Histogram,
     Registry,
-    SpanEvent,
     bucket_index,
     bucket_lo,
+)
+from repro.telemetry import spans as spans_mod
+from repro.telemetry.catalog import CATALOG
+
+# The spans and counters that split a decode and time row building, the
+# group-by and the collector: none of them may fold into a phase.
+STAGE_NAMES = (
+    "repro.decode.prepare",
+    "repro.decode.wait",
+    "repro.decode.materialize",
+    "repro.decode.kernel_rows",
+    "repro.decode.kernel_rows_padded",
+    "repro.db.aggregate",
+    "repro.runtime.gc",
 )
 
 
@@ -128,51 +146,153 @@ def test_merge_is_lossless_commutative_associative():
     assert _hist_eq(before, _hist_from(sa))
 
 
-# -- event ring -----------------------------------------------------------
+# -- spans and the profiler trace ------------------------------------------
 
 
-def test_event_log_wraparound():
-    log = EventLog(capacity=8)
-    n = 2 * 8 + 3
-    for i in range(n):
-        log.append(SpanEvent(i, f"ev{i}", 0, i * 10, 5))
-    assert len(log) == 8
-    assert log.total == n
-    evs = log.events()
-    # oldest dropped, order kept: the retained tail is the last 8 appends
-    assert [e.seq for e in evs] == list(range(n - 8, n))
+def _host_events(log_dir):
+    """``{name: (start_ns, end_ns)}`` of the ``repro.`` events on the host
+    plane of the trace recorded under ``log_dir`` (last one of a name)."""
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    profile = ProfileData.from_file(path[0])
+    out = {}
+    for plane in profile.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("repro."):
+                        out[e.name] = (e.start_ns, e.start_ns + e.duration_ns)
+    return out
 
 
-def test_span_nesting_depth_and_histogram():
-    with telemetry.span("repro.test.outer"):
-        with telemetry.span("repro.test.inner"):
-            pass
-    evs = [e for e in telemetry.EVENTS.events() if e.name.startswith("repro.test.")]
-    # inner closes first, one level deeper
-    assert [(e.name, e.depth) for e in evs] == [
-        ("repro.test.inner", 1),
-        ("repro.test.outer", 0),
-    ]
+def test_span_nesting_depth_and_histogram(tmp_path):
+    jax = pytest.importorskip("jax")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.span("repro.test.outer"):
+            with telemetry.span("repro.test.inner"):
+                gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(str(tmp_path))
+    (o0, o1), (i0, i1) = ev["repro.test.outer"], ev["repro.test.inner"]
+    assert o0 <= i0 <= i1 <= o1  # the inner span sits inside the outer
+    g0, g1 = ev["repro.runtime.gc"]  # the collector's span, inside both
+    assert i0 <= g0 <= g1 <= i1
     assert telemetry.REGISTRY.histogram("repro.test.outer").count == 1
+    assert telemetry.REGISTRY.histogram("repro.test.inner").count == 1
 
 
-def test_disabled_mode_is_inert():
+class _SpyAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``."""
+
+    recording = False
+    opened: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.recording
+
+    def __enter__(self):
+        self.opened.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.opened.append(("exit", self.name))
+
+
+def test_span_annotates_only_while_a_trace_records(monkeypatch):
+    monkeypatch.setattr(spans_mod, "_annotation_cls", _SpyAnnotation)
+    monkeypatch.setattr(_SpyAnnotation, "opened", [])
+    with telemetry.span("repro.test.quiet"):
+        pass
+    assert _SpyAnnotation.opened == []
+    assert telemetry.REGISTRY.histogram("repro.test.quiet").count == 1
+    monkeypatch.setattr(_SpyAnnotation, "recording", True)
+    with telemetry.span("repro.test.loud"):
+        pass
+    assert _SpyAnnotation.opened == [("enter", "repro.test.loud"),
+                                     ("exit", "repro.test.loud")]
+
+
+def test_gc_span_times_each_collection():
+    h = telemetry.REGISTRY.histogram("repro.runtime.gc")
+    before = h.count
+    gc.collect()
+    assert h.count >= before + 1 and h.sum_ns > 0
+
+
+def test_disabled_mode_is_inert(monkeypatch):
+    monkeypatch.setattr(spans_mod, "_annotation_cls", _SpyAnnotation)
+    monkeypatch.setattr(_SpyAnnotation, "opened", [])
+    monkeypatch.setattr(_SpyAnnotation, "recording", True)
     c = telemetry.counter("repro.test.c")
     h = telemetry.histogram("repro.test.h")
+    gc_h = telemetry.REGISTRY.histogram("repro.runtime.gc")
     prev = telemetry.set_enabled(False)
     try:
-        assert telemetry.clock() == 0
         c.add(5)
         h.observe(123)
-        h.observe_since(0)
-        telemetry.record("repro.test.h", 0)
         with telemetry.span("repro.test.h"):
-            pass
+            gc.collect()
         assert c.value == 0
         assert h.count == 0
-        assert telemetry.EVENTS.total == 0
+        assert gc_h.count == 0  # the collector's hook records nothing either
+        assert _SpyAnnotation.opened == []  # nor does anything reach a trace
     finally:
         telemetry.set_enabled(prev)
+
+
+def test_stage_names_are_catalogued_and_fold_into_no_phase():
+    prefixes = tuple(p for ps in PHASE_SOURCES.values() for p in ps)
+    for name in STAGE_NAMES:
+        assert name in CATALOG
+        assert not name.startswith(prefixes), name
+
+
+def test_pallas_decode_fills_its_stage_spans_and_row_counters():
+    pytest.importorskip("jax")
+    rng = np.random.default_rng(3)
+    rows = [{"id": i, "qty": int(rng.integers(1, 100)),
+             "amount": float(np.round(rng.uniform(0.01, 999.99), 2))}
+            for i in range(600)]
+    schema = [ColumnSpec("id", "int"), ColumnSpec("qty", "int"),
+              ColumnSpec("amount", "float", precision=0.01)]
+    plan = TableCodec.fit(rows, schema, sample=256).compile()
+    assert plan.pallas_ok
+    syms, ok = plan.encode_rows(rows)
+    codes, offsets = plan.encode_batch(syms[ok])
+    sel = np.arange(300)
+    telemetry.reset()
+    out = plan.decode_select(codes, offsets, sel, backend="pallas")
+    np.testing.assert_array_equal(out, syms[ok][sel])
+    reg = telemetry.REGISTRY
+    prepare = reg.histogram("repro.decode.prepare")
+    wait = reg.histogram("repro.decode.wait")
+    decode = reg.histogram("repro.core.decode")
+    assert prepare.count == wait.count == decode.count == 1
+    assert prepare.sum_ns + wait.sum_ns <= decode.sum_ns
+    assert reg.counter("repro.decode.kernel_rows").value == 300
+    assert reg.counter("repro.decode.kernel_rows_padded").value == 512
+    # the numpy path has no kernel: no stage spans, no kernel rows
+    plan.decode_select(codes, offsets, sel, backend="numpy")
+    assert prepare.count == 1 and decode.count == 2
+    assert reg.counter("repro.decode.kernel_rows").value == 300
+
+
+def test_materialize_span_covers_row_building():
+    rows = [{"id": i, "qty": i % 7} for i in range(200)]
+    plan = TableCodec.fit(rows, [ColumnSpec("id", "int"),
+                                 ColumnSpec("qty", "int")]).compile()
+    syms, ok = plan.encode_rows(rows)
+    telemetry.reset()
+    assert plan.decode_syms_to_rows(syms[:5]) == rows[:5]
+    assert plan.decode_syms_to_rows(syms[:5], columns=["qty"]) == [
+        {"qty": r["qty"]} for r in rows[:5]]
+    assert telemetry.REGISTRY.histogram("repro.decode.materialize").count == 2
 
 
 # -- ScanStats on shared registry counters --------------------------------

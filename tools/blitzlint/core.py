@@ -85,9 +85,9 @@ class LintConfig:
         "src/repro/core/blitzcrank.py",
         "src/repro/core/arena.py",
     )
-    # Trees where wall-clock reads must go through the telemetry clock
-    # (so disabled-mode stays zero-cost and phase attribution stays
-    # consistent).  The telemetry package itself implements the clock.
+    # Trees where regions must be timed by telemetry spans (so
+    # disabled-mode stays zero-cost and phase attribution stays
+    # consistent).  The telemetry package itself implements the spans.
     clocked_trees: Tuple[str, ...] = (
         "src/repro/core/",
         "src/repro/db/",

@@ -1,7 +1,8 @@
-"""BL007 clean: the telemetry clock."""
+"""BL007 clean: a telemetry span."""
 
 from repro import telemetry
 
 
-def stamp():
-    return telemetry.clock()
+def timed(work):
+    with telemetry.span("repro.core.encode"):
+        return work()

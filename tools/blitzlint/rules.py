@@ -179,7 +179,7 @@ class HotLoopRule(Rule):
 # BL002 — telemetry-name discipline
 # ---------------------------------------------------------------------------
 
-_TELEMETRY_FACTORIES = frozenset({"counter", "gauge", "histogram", "span", "record"})
+_TELEMETRY_FACTORIES = frozenset({"counter", "histogram", "span"})
 
 
 @register
@@ -608,10 +608,10 @@ class RawClockRule(Rule):
     id = "BL007"
     title = "raw time.* read in a telemetry-clocked module"
     rationale = (
-        "Hot-path timing goes through telemetry.clock()/observe_since so "
-        "disabled mode stays zero-cost and phase attribution sees every "
-        "sample; a raw time.time() is invisible to the breakdown and "
-        "keeps costing syscalls when telemetry is off."
+        "Hot-path timing goes through telemetry.span so disabled mode "
+        "stays zero-cost and phase attribution and the profiler trace see "
+        "every region; a raw time.time() is invisible to both and keeps "
+        "costing syscalls when telemetry is off."
     )
 
     def applies_to(self, ctx: LintContext) -> bool:
@@ -630,6 +630,6 @@ class RawClockRule(Rule):
                     ctx,
                     node,
                     f"time.{node.func.attr}() bypasses the telemetry clock "
-                    "(use telemetry.clock()/observe_since, or waive with "
+                    "(use telemetry.span, or waive with "
                     "why wall time is data here)",
                 )
