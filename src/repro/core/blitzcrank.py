@@ -56,6 +56,7 @@ _C_SPILL_BLOCKS = telemetry.counter("repro.residency.spill.blocks")
 _C_FAULT_BLOCKS = telemetry.counter("repro.residency.fault_in.blocks")
 _C_MIGRATED = telemetry.counter("repro.store.migrate.rows")
 _C_PALLAS_DOWNGRADE = telemetry.counter("repro.plan.pallas_downgrade")
+_C_SCALAR_ROWS = telemetry.counter("repro.core.decode.scalar_rows")
 
 
 @dataclasses.dataclass
@@ -324,52 +325,55 @@ class TableCodec:
     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Batch-compress rows at single-tuple granularity.
 
-        Returns ``(codes uint16, offsets int64[N+1], fast bool[N])`` — a CSR
-        arena where row ``r`` owns ``codes[offsets[r]:offsets[r+1]]``.
-        Conforming rows go through one vectorized ``encode_batch`` call;
-        the rest are scalar-encoded one block each (identical stream format).
-        Requires ``block_tuples == 1``.
+        Returns ``(codes uint16, offsets int64[N+1], template int8[N])`` — a
+        CSR arena where row ``r`` owns ``codes[offsets[r]:offsets[r+1]]``,
+        and the plan-family member that encoded each row (-1: it escaped).
+        The rows each member takes go through one vectorized
+        ``encode_batch`` call; the rest are scalar-encoded one block each
+        (identical stream format).  Requires ``block_tuples == 1``.
         """
         if self.block_tuples != 1:
             raise ValueError("compress_rows requires block_tuples == 1")
         n = len(rows)
-        offsets = np.zeros(n + 1, np.int64)
-        fast = np.zeros(n, bool)
+        tmpl = np.full(n, -1, np.int8)
         if n == 0:
-            return np.zeros(0, np.uint16), offsets, fast
+            return np.zeros(0, np.uint16), np.zeros(1, np.int64), tmpl
         plan = self.compile()
-        fcodes = foff = None
+        # (row positions, codes, offsets) of each group of rows
+        groups: List[Tuple[np.ndarray, np.ndarray, np.ndarray]] = []
         if plan is not None:
-            syms, fast = plan.encode_rows(rows)
-            if fast.all():
-                # All rows conform: the batch CSR is already the arena
-                # layout — skip the per-row interleave entirely.
-                fcodes, foff = plan.encode_batch(syms)
-                codes = checked_astype(
-                    fcodes, np.uint16, where="compress_rows codes"
-                )
-                return codes, np.asarray(foff, np.int64), fast
-            if fast.any():
-                fcodes, foff = plan.encode_batch(syms[fast])
-        chunks: List[np.ndarray] = []
-        fi = 0
-        pos = 0
-        # blitzlint: waive[BL001] -- interleaves vectorized conforming blocks with per-row escape encodes
-        for r in range(n):
-            if fast[r]:
-                c = fcodes[foff[fi]:foff[fi + 1]]
-                fi += 1
-            else:
-                c = self._scalar_compress([rows[r]])
-            chunks.append(c)
-            pos += len(c)
-            offsets[r + 1] = pos
-        codes = checked_astype(
-            np.concatenate(chunks) if chunks else np.zeros(0, np.uint16),
-            np.uint16,
-            where="compress_rows codes",
-        )
-        return codes, offsets, fast
+            t_rows, parts = plan.encode_family(rows)
+            tmpl = t_rows.astype(np.int8)
+            for t, syms in sorted(parts.items()):
+                fcodes, foff = plan.family[t].encode_batch(syms)
+                if syms.shape[0] == n:
+                    # One member took every row: its batch CSR is already
+                    # the arena layout — skip the interleave entirely.
+                    codes = checked_astype(
+                        fcodes, np.uint16, where="compress_rows codes"
+                    )
+                    return codes, np.asarray(foff, np.int64), tmpl
+                groups.append((np.nonzero(tmpl == t)[0], fcodes,
+                               np.asarray(foff, np.int64)))
+        esc = np.nonzero(tmpl < 0)[0]
+        if esc.size:
+            # the escape path: rows no plan member takes, one block each
+            chunks = [self._scalar_compress([rows[i]]) for i in esc.tolist()]
+            eoff = np.zeros(esc.size + 1, np.int64)
+            np.cumsum([c.size for c in chunks], out=eoff[1:])
+            groups.append((esc, np.concatenate(chunks), eoff))
+        lens = np.zeros(n, np.int64)
+        for pos, _, off in groups:
+            lens[pos] = np.diff(off)
+        offsets = np.zeros(n + 1, np.int64)
+        np.cumsum(lens, out=offsets[1:])
+        codes = np.empty(int(offsets[-1]), np.uint16)
+        for pos, c, off in groups:
+            # element k of a group's run j lands at offsets[pos[j]] + k - off[j]
+            codes[np.repeat(offsets[pos] - off[:-1], np.diff(off))
+                  + np.arange(off[-1])] = checked_astype(
+                c, np.uint16, where="compress_rows codes")
+        return codes, offsets, tmpl
 
     def decompress_rows(
         self,
@@ -377,17 +381,19 @@ class TableCodec:
         offsets: np.ndarray,
         indices: Sequence[int],
         backend: str = "numpy",
+        template: int = 0,
     ) -> List[Dict[str, Any]]:
         """Batch random-access decode from a CSR arena (compiled codecs only).
 
-        Every indexed row must have been encoded on the fast path (its codes
-        follow the plan's fixed slot layout).  ``backend`` is ``"numpy"`` or
-        ``"pallas"`` (compiled on a TPU, interpret mode on CPU, verified
-        against numpy).
+        Every indexed row must have been encoded by plan-family member
+        ``template`` (its codes follow that member's fixed slot layout).
+        ``backend`` is ``"numpy"`` or ``"pallas"`` (compiled on a TPU,
+        interpret mode on CPU, verified against numpy).
         """
         plan = self.compile()
         if plan is None:
             raise RuntimeError(f"codec did not compile: {self._plan_reason}")
+        plan = plan.family[template]
         syms = plan.decode_select(
             checked_asarray(codes, np.uint16, where="decompress_rows codes"),
             np.asarray(offsets, np.int64),
@@ -398,6 +404,7 @@ class TableCodec:
 
     def decompress_block(self, codes: np.ndarray, n_rows: int) -> List[Dict[str, Any]]:
         with telemetry.span("repro.core.decode.scalar_block"):
+            _C_SCALAR_ROWS.add(n_rows)
             self._reset_block_state()
             dec = BlockDecoder(
                 codes.tolist() if isinstance(codes, np.ndarray) else codes, self.lam
@@ -486,6 +493,9 @@ class CompressedTable:
         # so older blocks stay readable after a refit installs a new codec.
         self._codecs: List[TableCodec] = [codec]
         self._plan_ver = np.zeros(1023, dtype=np.uint16)
+        # Per-block plan-family member of a fast block (DESIGN.md §2.3):
+        # which template's slot layout its codes follow.
+        self._tmpl = np.zeros(1023, dtype=np.uint8)
         self.use_pallas = use_pallas
         self.arena = np.zeros(capacity_hint, dtype=np.uint16)
         self.used = 0
@@ -813,6 +823,7 @@ class CompressedTable:
             self._offsets[first + 1:first + 1 + n] = base + np.cumsum(lens)
             self._fast[first:first + n] = self._fast[blocks]
             self._plan_ver[first:first + n] = self._plan_ver[blocks]
+            self._tmpl[first:first + n] = self._tmpl[blocks]
             self._zone_union(first, blocks)
             rows = self._block2row[blocks]
             self._init_new_blocks(first, n, rows)
@@ -877,6 +888,9 @@ class CompressedTable:
             ver = np.zeros(cap - 1, dtype=np.uint16)
             ver[:self.n_blocks] = self._plan_ver[:self.n_blocks]
             self._plan_ver = ver
+            tmpl = np.zeros(cap - 1, dtype=np.uint8)
+            tmpl[:self.n_blocks] = self._tmpl[:self.n_blocks]
+            self._tmpl = tmpl
             if self._res is not None:
                 nb = self.n_blocks
                 resident = np.ones(cap - 1, dtype=bool)
@@ -1021,6 +1035,7 @@ class CompressedTable:
         n_rows: int,
         fast: bool,
         rows: Optional[Sequence[Dict[str, Any]]] = None,
+        template: int = 0,
     ) -> None:
         self._append_codes(codes)
         self._grow_index(1)
@@ -1028,6 +1043,7 @@ class CompressedTable:
         self._offsets[self.n_blocks] = self.used
         self._fast[self.n_blocks - 1] = fast
         self._plan_ver[self.n_blocks - 1] = self.current_version
+        self._tmpl[self.n_blocks - 1] = template
         if rows is not None:
             self._zone_widen(np.full(len(rows), self.n_blocks - 1, np.int64), rows)
         self.block_rows.append(n_rows)
@@ -1048,6 +1064,11 @@ class CompressedTable:
         """Per-block flag: True when the block decodes on the compiled path."""
         return self._fast[:self.n_blocks]
 
+    @property
+    def block_templates(self) -> np.ndarray:
+        """Per-block plan-family member ``uint8[n_blocks]`` (fast blocks)."""
+        return self._tmpl[:self.n_blocks]
+
     # -- write path ------------------------------------------------------
     def append(self, row: Dict[str, Any]) -> None:
         self._pending.append(row)
@@ -1063,14 +1084,15 @@ class CompressedTable:
                 self.append(r)
             return
         self.flush()
-        codes, offsets, fast = self.codec.compress_rows(rows)
+        codes, offsets, tmpl = self.codec.compress_rows(rows)
         base = self.used
         self._append_codes(codes)
         n = len(rows)
         self._grow_index(n)
         self._offsets[self.n_blocks + 1:self.n_blocks + 1 + n] = base + offsets[1:]
-        self._fast[self.n_blocks:self.n_blocks + n] = fast
+        self._fast[self.n_blocks:self.n_blocks + n] = tmpl >= 0
         self._plan_ver[self.n_blocks:self.n_blocks + n] = self.current_version
+        self._tmpl[self.n_blocks:self.n_blocks + n] = np.maximum(tmpl, 0)
         self._zone_widen(np.arange(self.n_blocks, self.n_blocks + n), rows)
         self._init_new_blocks(
             self.n_blocks, n, np.arange(self._rows_stored, self._rows_stored + n)
@@ -1090,11 +1112,13 @@ class CompressedTable:
             return
         rows, self._pending = self._pending, []
         # Scalar encode (cheapest for one row; identical codes either way),
-        # plus a cheap pure-Python conformance probe for the fast flag.
+        # plus a cheap pure-Python probe for the plan-family member.
         plan = self.codec.compile()
-        fast = (plan is not None and len(rows) == 1 and plan.row_conforms(rows[0]))
+        tmpl = (plan.row_template(rows[0])
+                if plan is not None and len(rows) == 1 else -1)
         codes = self.codec._scalar_compress(rows)
-        self._append_block(codes, len(rows), fast, rows=rows)
+        self._append_block(codes, len(rows), tmpl >= 0, rows=rows,
+                           template=max(tmpl, 0))
         self._enforce_budget()
 
     def __len__(self) -> int:
@@ -1177,10 +1201,11 @@ class CompressedTable:
         """Batched point gets (``None`` for tombstoned rows).
 
         Rows in plan-conforming single-tuple blocks decode with ONE
-        ``decode_select`` call *per plan version present in the batch*
-        (a block's fast flag certifies it against the plan it was encoded
-        with); the rest fall back to per-block scalar decode (each touched
-        block decoded once, under its own version's codec).
+        ``decode_select`` call *per (plan version, family member) present in
+        the batch* (a block's fast flag and template certify it against the
+        plan it was encoded with); the rest fall back to per-block scalar
+        decode (each touched block decoded once, under its own version's
+        codec).
         """
         self.sanitize_boundary("get_many")
         idx_arr = np.asarray(list(indices), dtype=np.int64)
@@ -1214,15 +1239,17 @@ class CompressedTable:
                 fmask[stored] = self._fast[blks[stored]]
             fast_pos = np.nonzero(fmask)[0]
             if fast_pos.size:
-                vers = self._plan_ver[blks[fast_pos]]
-                for v in np.unique(vers):
-                    sel = fast_pos[vers == v]
-                    codec_v = self._codecs[v]
+                fb = blks[fast_pos]
+                route = self._plan_ver[fb].astype(np.int64) << 8 | self._tmpl[fb]
+                for g in np.unique(route).tolist():
+                    sel = fast_pos[route == g]
+                    codec_v = self._codecs[g >> 8]
                     rows = codec_v.decompress_rows(
                         self.arena[:self.used],
                         self.block_offsets,
                         blks[sel],
                         backend=self._resolve_backend(backend, sel.size, codec_v),
+                        template=g & 0xFF,
                     )
                     # blitzlint: waive[BL001] -- scatters scalar-decoded escape rows back into the batched result
                     for j, r in zip(sel.tolist(), rows):
@@ -1310,14 +1337,15 @@ class CompressedTable:
         if np.unique(idx).size != n:
             # duplicates would double-count dead bytes and orphan runs
             raise ValueError("replace_many indices must be unique")
-        codes, offsets, fast = self.codec.compress_rows(list(rows))
+        codes, offsets, tmpl = self.codec.compress_rows(list(rows))
         base = self.used
         self._append_codes(codes)
         self._grow_index(n)
         first = self.n_blocks
         self._offsets[first + 1:first + 1 + n] = base + offsets[1:]
-        self._fast[first:first + n] = fast
+        self._fast[first:first + n] = tmpl >= 0
         self._plan_ver[first:first + n] = self.current_version
+        self._tmpl[first:first + n] = np.maximum(tmpl, 0)
         self._zone_widen(np.arange(first, first + n), list(rows))
         self._init_new_blocks(first, n, idx)
         self.n_blocks += n
@@ -1397,6 +1425,8 @@ class CompressedTable:
             fast[:nb] = self._fast[blks]
             ver = np.zeros(offs.size - 1, dtype=np.uint16)
             ver[:nb] = self._plan_ver[blks]  # tags survive compaction
+            tmpl = np.zeros(offs.size - 1, dtype=np.uint8)
+            tmpl[:nb] = self._tmpl[blks]
             if res is not None:
                 resident = np.ones(offs.size - 1, dtype=bool)
                 resident[:nb] = res_mask
@@ -1414,7 +1444,7 @@ class CompressedTable:
                 res.hand = 0
             self.arena, self.used = arena, total
             self._offsets, self._fast, self.n_blocks = offs, fast, nb
-            self._plan_ver = ver
+            self._plan_ver, self._tmpl = ver, tmpl
             self._zone_rebuild(blks, nb)
             self.block_rows = [1] * nb
             self._row2block[:nrows] = -1
@@ -1481,6 +1511,7 @@ class CompressedTable:
             "offsets": self._offsets[:nb + 1].copy(),
             "fast": self._fast[:nb].copy(),
             "plan_ver": self._plan_ver[:nb].copy(),
+            "template": self._tmpl[:nb].copy(),
             "block_rows": list(self.block_rows),
             "row2block": self._row2block[:n].copy(),
             "rows_stored": n,
@@ -1554,6 +1585,9 @@ class CompressedTable:
         t._fast[:nb] = state["fast"]
         t._plan_ver = np.zeros(cap - 1, dtype=np.uint16)
         t._plan_ver[:nb] = state["plan_ver"]
+        t._tmpl = np.zeros(cap - 1, dtype=np.uint8)
+        if "template" in state:  # older snapshots predate plan families
+            t._tmpl[:nb] = state["template"]
         t.n_blocks = nb
         t.block_rows = list(state["block_rows"])
         n = int(state["rows_stored"])
@@ -1618,7 +1652,9 @@ class CompressedTable:
         """Compressed footprint: code arena + block index + unflushed rows.
 
         Offsets are counted at 4 B each (a uint32 arena index suffices for
-        <8 GiB of codes) plus 1 bit per block for the fast flag; pending
+        <8 GiB of codes) plus the bits per block that tell a slow block from
+        the fast ones of each stored plan-family template (1 bit, the fast
+        flag, while every fast block is the modal plan's); pending
         rows sit uncompressed and are charged at their raw size.  At
         single-tuple granularity the row->block indirection (mutation
         support) adds 4 B per logical row.  Once a refit installs a second
@@ -1638,6 +1674,8 @@ class CompressedTable:
         ver_tags = self.n_blocks if len(self._codecs) > 1 else 0
         res_meta = 9 * self.n_blocks if self._res is not None else 0
         zone_bytes = (16 * len(self._zone_cols) * self._zone_chunks(self.n_blocks))
-        return (self.used * 2 + 4 * (self.n_blocks + 1)
-                + (self.n_blocks + 7) // 8 + indirection + ver_tags
+        nb = self.n_blocks
+        routes = int(self._tmpl[:nb].max()) + 1 if nb else 1  # + the slow state
+        return (self.used * 2 + 4 * (nb + 1)
+                + (nb * routes.bit_length() + 7) // 8 + indirection + ver_tags
                 + res_meta + zone_bytes + pending)

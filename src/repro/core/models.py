@@ -396,6 +396,11 @@ class StringModel:
         # near-constant word positions and lower them to a vectorized
         # character-matrix check.
         self.pos_words: Dict[int, List[Counter]] = {}
+        # Joint template histogram: (word count, digit-or-word flag at each
+        # word position) per row.  The slot-plan compiler lowers the most
+        # frequent templates to a family of plans (plan.py), since rows of
+        # one template share one fixed symbol layout.
+        self.templates: Counter = Counter()
         for idx, s in enumerate(values):
             if idx % max(1, block_tuples) == 0:
                 queue.clear()
@@ -415,6 +420,9 @@ class StringModel:
             words_row = self.pos_words.setdefault(
                 row_n, [Counter() for _ in range(row_n)]
             )
+            self.templates[
+                (row_n, tuple(_is_digit_token(tok) for tok in segs[::2]))
+            ] += 1
             for t, tok in enumerate(segs):
                 if t % 2 == 0:
                     if _is_digit_token(tok):

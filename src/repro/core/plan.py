@@ -16,21 +16,27 @@ Plan-ability rules (DESIGN.md §2.3):
   which the tuple-parallel layout cannot reproduce;
 * every column model lowers: categorical (1 slot), numeric two-level
   (1 + len(l2) slots), conditional categorical with an earlier categorical
-  (or conditional) parent chain (1 CondSlot), and format-fixed strings
-  (fixed word/delimiter template);
+  (or conditional) parent chain (1 CondSlot), and strings (one fixed
+  word/delimiter template per plan);
 * time-series models are stateful across rows and always fall back.
 
-Plan-ability is *per schema*; conformance is *per row*: a row whose value
-escapes (unseen category, out-of-range numeric, off-template string) is
-encoded by the scalar path and its block flagged slow.  Fast and slow blocks
-share one code-stream format — the plan emits bit-identical codes to the
-scalar encoder — so the flag only routes decoding.
+A string column whose rows come in a few word templates lowers to each of
+them, so a table compiles to a small *family* of plans (the cross product
+of its columns' templates, modal plan first); a block records which member
+encoded it.  Plan-ability is *per schema*; conformance is *per row*: a row
+that no member takes (unseen category, out-of-range numeric, a string of
+no lowered template) is encoded by the scalar path and its block flagged
+slow.  Fast and slow blocks share one code-stream format — every member
+emits bit-identical codes to the scalar encoder — so the flag and the
+template id only route decoding.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import math
+from collections import Counter
 
 import numpy as np
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
@@ -53,6 +59,22 @@ from .vectorized import CondSlot
 
 MAX_COND_KEYS = 1 << 16  # cap on enumerated parent-chain combinations
 
+# A string column lowers to its most frequent word templates (word count and
+# digit-or-word kind at each position), modal template first.  Templates are
+# added until they cover TEMPLATE_COVERAGE of the fit's rows: past that the
+# rows left are too few to repay a plan.
+TEMPLATE_COVERAGE = 0.999
+# At most this many per column: each template present in a read batch costs
+# one more decode call, and four cover the word-count spread of a
+# variable-length name in a fixed-format string.
+MAX_COLUMN_TEMPLATES = 4
+# A template seen in fewer of the fit's rows than this is not worth a plan;
+# its rows stay on the escape path.
+MIN_TEMPLATE_SHARE = 0.001
+# Plans per table, the cross product of its columns' templates taken in
+# descending joint share: more would add decode calls for rare row shapes.
+MAX_FAMILY = 8
+
 # Hot-path metric handles (DESIGN.md §9).  The ``repro.core.encode*`` /
 # ``repro.core.decode*`` spans are leaf phases of the wall-time breakdown
 # and pallas_pack is a jit-compile event; the ``repro.decode.*`` spans
@@ -62,6 +84,7 @@ _C_DECODE_ROWS = telemetry.counter("repro.core.decode.rows")
 _C_KERNEL_ROWS = telemetry.counter("repro.decode.kernel_rows")
 _C_KERNEL_ROWS_PADDED = telemetry.counter("repro.decode.kernel_rows_padded")
 _C_PALLAS_PACK = telemetry.counter("repro.plan.pallas_pack.events")
+_C_TEMPLATE_ROWS = telemetry.counter("repro.plan.template_rows")
 
 
 class PlanFallback(Exception):
@@ -259,30 +282,78 @@ class _CondPlan:
 _DIGIT_CHARS = np.array(list("0123456789"), dtype=object)
 
 
+Template = Tuple[int, Tuple[bool, ...]]  # (word count, digit flag per word)
+
+
+def _modal_template(m: StringModel) -> Template:
+    """The modal word count ``W`` with each position's modal kind."""
+    counts = getattr(m, "n_words_counts", None)
+    if not counts:
+        raise PlanFallback("string model has no template statistics")
+    W = int(counts.most_common(1)[0][0])
+    per_pos = getattr(m, "pos_kinds", {}).get(W)
+    esc_digits = getattr(m.dict_model, "esc_digits", None)
+    digits = []
+    for t in range(max(W, 0)):
+        kind = -1
+        if per_pos is not None and t < len(per_pos) and per_pos[t]:
+            kind = int(per_pos[t].most_common(1)[0][0])
+        digits.append(kind >= 1 and esc_digits is not None)
+    return W, tuple(digits)
+
+
+def _column_templates(m: StringModel) -> List[Tuple["_StrPlan", float]]:
+    """A string column's lowerings with each one's share of the fit's rows.
+
+    The modal template comes first (the column's plan before families);
+    then the fit's joint template histogram in descending frequency, until
+    the templates cover ``TEMPLATE_COVERAGE`` of the rows, reach
+    ``MAX_COLUMN_TEMPLATES``, or fall below ``MIN_TEMPLATE_SHARE``.
+    """
+    first = _modal_template(m)
+    hist = getattr(m, "templates", None) or {}
+    total = sum(hist.values())
+    share = hist.get(first, 0) / total if total else 1.0
+    out = [(_StrPlan(m, first), share)]
+    covered = share
+    for tpl, count in sorted(hist.items(), key=lambda kv: -kv[1]):
+        if len(out) >= MAX_COLUMN_TEMPLATES or covered >= TEMPLATE_COVERAGE:
+            break
+        share = count / total
+        if share < MIN_TEMPLATE_SHARE:
+            break
+        if tpl == first:
+            continue
+        try:
+            out.append((_StrPlan(m, tpl), share))
+        except PlanFallback:
+            continue  # a shape the scalar coder has no fixed layout for
+        covered += share
+    return out
+
+
 class _StrPlan:
     """StringModel -> fixed word/delimiter template slots.
 
     Requires ``block_tuples == 1`` (enforced at plan level): the per-block
     prefix queue is then always empty at encode time, so the match slot is
     the constant "no prefix" symbol and no prefix-length slots are emitted.
-    The template fixes ``W`` = the modal word count of the training column;
-    each word position is lowered in its *modal kind*: a dictionary word
-    (one dict-coder slot) or an all-digit token of up to ``cap`` digits
-    (constant ``esc_digits`` + length slots, then ``cap`` uniform digit
-    slots — the scalar encoder's cap-padded digit path, flattened, so
-    street numbers and sku/phone runs of varying width share one layout).
+    The template fixes the word count ``W`` and each word position's kind:
+    a dictionary word (one dict-coder slot) or an all-digit token of up to
+    ``cap`` digits (constant ``esc_digits`` + length slots, then ``cap``
+    uniform digit slots — the scalar encoder's cap-padded digit path,
+    flattened, so street numbers and sku/phone runs of varying width share
+    one layout).  The scalar coder's symbols for a row depend only on the
+    row's ``(W, kinds)``, so a plan per template emits them bit for bit.
     Rows with a different segment count, a kind mismatch or over-cap digit
     run at any position, dictionary-miss words, or escape delimiters are
     non-conforming.
     """
 
-    def __init__(self, model: StringModel) -> None:
+    def __init__(self, model: StringModel, template: Optional[Template] = None) -> None:
         m = model
-        counts = getattr(m, "n_words_counts", None)
-        if not counts:
-            raise PlanFallback("string model has no template statistics")
         self.m = m
-        self.W = int(counts.most_common(1)[0][0])
+        self.W, digits = template if template is not None else _modal_template(m)
         if self.W < 1:
             raise PlanFallback("string template has no words")
         n_m = m.n_model
@@ -309,11 +380,14 @@ class _StrPlan:
         for t in range(self.W):
             mode: Optional[int] = None
             modal: Optional[int] = None
-            if per_pos is not None and t < len(per_pos) and per_pos[t]:
-                kind = int(per_pos[t].most_common(1)[0][0])
-                if kind >= 1 and self._esc_digits is not None:
-                    mode = int(m.digit_cap(self.W, t))
-                    modal = kind
+            if digits[t]:
+                cap = int(m.digit_cap(self.W, t))
+                if self._esc_digits is None or cap < 1:
+                    raise PlanFallback("string template digit run has no cap")
+                mode = cap
+                kinds = per_pos[t] if per_pos is not None and t < len(per_pos) else {}
+                lens = Counter({k: c for k, c in kinds.items() if k >= 1})
+                modal = int(lens.most_common(1)[0][0]) if lens else None
             self._modes.append(mode)
             self._digit_modal.append(modal)
         # Slot offsets (relative to the first template slot) of each word
@@ -588,7 +662,13 @@ def _build_cond(
 
 
 class TablePlan:
-    """A compiled codec: static slots + vectorized value<->symbol tables."""
+    """A compiled codec: static slots + vectorized value<->symbol tables.
+
+    One member of the table's plan family (:func:`compile_plan`):
+    ``family`` lists every member, modal plan first, and ``template`` is
+    this member's index there — the id a block encoded under it stores.
+    The first member holds the escape counters for the whole family.
+    """
 
     def __init__(
         self, codec: Any, lowerings: List[Tuple[str, Any, int]]
@@ -598,6 +678,9 @@ class TablePlan:
         self.lowerings = lowerings
         self.by_column = {name: (cp, off) for name, cp, off in lowerings}
         self.lam = codec.lam
+        self.family: List[TablePlan] = [self]
+        self.template = 0
+        self._family_bits: Optional[Tuple[int, ...]] = None
         # Per-column escape counters (§5-style dynamic value sets): how many
         # values failed to lower at encode time — the signal the adaptive
         # maintenance layer (DESIGN.md §4) watches to decide a column's model
@@ -675,28 +758,92 @@ class TablePlan:
         return {k: v / n for k, v in self.window_escapes.items()}
 
     # -- encode ----------------------------------------------------------
+    def _encode(
+        self, rows: Sequence[Dict[str, Any]]
+    ) -> Tuple[np.ndarray, np.ndarray, Dict[str, np.ndarray]]:
+        """Rows -> (syms int64[N, S], conforming bool[N], per-column masks
+        of the rows each column refuses); charges nothing."""
+        n = len(rows)
+        cols = {name: [r[name] for r in rows] for name in self.order}
+        syms = np.zeros((n, self.S), np.int64)
+        ok = np.ones(n, bool)
+        refused: Dict[str, np.ndarray] = {}
+        for name, cp, off in self.lowerings:
+            try:
+                s_col, o = cp.encode(cols[name], cols)
+            except Exception:
+                refused[name] = np.ones(n, bool)
+                ok[:] = False
+                continue
+            syms[:, off:off + cp.n_slots] = s_col
+            if not o.all():
+                refused[name] = ~o
+            ok &= o
+        return syms, ok, refused
+
+    def _route(
+        self, rows: Sequence[Dict[str, Any]]
+    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, Dict[int, np.ndarray]]:
+        """Encode under this member, then offer the rows it refuses to the
+        family's other members in order, each seeing only the rows the
+        earlier ones refused.
+
+        Returns this member's ``(syms, ok)``, each row's template (-1 when
+        no member takes it) and ``{template: syms of its rows in row
+        order}``.  Only rows no member takes are charged, per column as
+        this member refused them — the escape counters keep their meaning.
+        """
+        head = self.family[0]
+        head._note_rows(len(rows))
+        syms, ok, refused = self._encode(rows)
+        tmpl = np.where(ok, self.template, -1)
+        parts: Dict[int, np.ndarray] = {}
+        if ok.any():
+            parts[self.template] = syms if ok.all() else syms[ok]
+        rest = np.nonzero(~ok)[0]
+        for member in self.family:
+            if not rest.size:
+                break
+            if member is self:
+                continue
+            s_m, o_m, _ = member._encode([rows[i] for i in rest.tolist()])
+            if o_m.any():
+                tmpl[rest[o_m]] = member.template
+                parts[member.template] = s_m[o_m]
+            rest = rest[~o_m]
+        if rest.size:
+            for name, mask in refused.items():
+                misses = int(np.count_nonzero(mask[rest]))
+                if misses:
+                    head._charge(name, misses)
+        moved = int(np.count_nonzero(tmpl > 0))
+        if moved:
+            _C_TEMPLATE_ROWS.add(moved)
+        return syms, ok, tmpl, parts
+
     def encode_rows(self, rows: Sequence[Dict[str, Any]]
                     ) -> Tuple[np.ndarray, np.ndarray]:
-        """Rows -> (syms int64[N, S], conforming bool[N])."""
+        """Rows -> (syms int64[N, S], conforming bool[N]) under this plan.
+
+        A row this plan refuses is charged in :attr:`escape_counts` only
+        if no other member of the family takes it (see :meth:`encode_family`).
+        """
         with telemetry.span("repro.core.encode.scalar"):
-            n = len(rows)
-            self._note_rows(n)
-            cols = {name: [r[name] for r in rows] for name in self.order}
-            syms = np.zeros((n, self.S), np.int64)
-            ok = np.ones(n, bool)
-            for name, cp, off in self.lowerings:
-                try:
-                    s_col, o = cp.encode(cols[name], cols)
-                except Exception:
-                    self._charge(name, n)
-                    ok[:] = False
-                    continue
-                syms[:, off:off + cp.n_slots] = s_col
-                misses = int(n - np.count_nonzero(o))
-                if misses:
-                    self._charge(name, misses)
-                ok &= o
+            syms, ok, _, _ = self._route(rows)
         return syms, ok
+
+    def encode_family(
+        self, rows: Sequence[Dict[str, Any]]
+    ) -> Tuple[np.ndarray, Dict[int, np.ndarray]]:
+        """Rows -> (template int64[N], {template: syms of its rows}).
+
+        The modal plan tries every row; only the rows it refuses are tried
+        against the next members.  Template -1 marks a row no member takes:
+        it escapes to the scalar coder, charged exactly as before.
+        """
+        with telemetry.span("repro.core.encode.scalar"):
+            _, _, tmpl, parts = self.family[0]._route(rows)
+        return tmpl, parts
 
     def encode_batch(self, syms: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Symbols -> CSR ``(codes uint16, offsets int64[N+1])``."""
@@ -706,26 +853,46 @@ class TablePlan:
             _C_ENCODE_ROWS.add(syms.shape[0])
         return codes, offsets
 
-    def row_conforms(self, row: Dict[str, Any]) -> bool:
-        """Cheap scalar check: would this row take the fast path?
-
-        Pure-Python per-column checks (no numpy) so the per-insert cost is a
-        few dict lookups, not a 1-row batch encode.  Every non-conforming
-        column is charged in :attr:`escape_counts` — the same per-column
-        semantics as the batch ``encode_rows`` masks, so drift rates don't
-        depend on which encode path a row took.
-        """
-        self._note_rows(1)
-        ok = True
+    def _refused(self, row: Dict[str, Any]) -> List[str]:
+        """Columns of ``row`` this plan cannot lower (pure-Python checks)."""
+        bad = []
         for name, cp, _ in self.lowerings:
             try:
                 good = cp.conforms(row[name], row)
             except (TypeError, KeyError, ValueError):
                 good = False
             if not good:
-                self._charge(name)
-                ok = False
-        return ok
+                bad.append(name)
+        return bad
+
+    def row_template(self, row: Dict[str, Any]) -> int:
+        """Cheap scalar check: the template of the family member this row
+        takes, or -1 when it escapes.
+
+        Pure-Python per-column checks (no numpy) so the per-insert cost is a
+        few dict lookups, not a 1-row batch encode.  A row no member takes
+        is charged for every column this plan refuses — the same per-column
+        semantics as the batch :meth:`encode_rows` masks, so drift rates
+        don't depend on which encode path a row took.
+        """
+        head = self.family[0]
+        head._note_rows(1)
+        bad = self._refused(row)
+        if not bad:
+            return self.template
+        for member in self.family:
+            if member is not self and not member._refused(row):
+                if member.template:
+                    _C_TEMPLATE_ROWS.inc()
+                return member.template
+        for name in bad:
+            head._charge(name)
+        return -1
+
+    def row_conforms(self, row: Dict[str, Any]) -> bool:
+        """Would this row take this plan's fast path?  Charged as
+        :meth:`row_template` charges."""
+        return self.row_template(row) == self.template
 
     # -- decode ----------------------------------------------------------
     def decode_batch(self, codes: np.ndarray, offsets: np.ndarray,
@@ -773,31 +940,52 @@ class TablePlan:
             padded = max(BLOCK_T, 1 << (n - 1).bit_length())
             if padded != n:
                 rows = np.concatenate([rows, np.full(padded - n, rows[-1], np.int64)])
+            # The kernel's slots are the family's (kernel_m_bits): at least
+            # this member's; the slots past its own decode zeros, unread.
+            tables, m_bits = self.pallas_tables()
             starts = offsets[rows]
             lens = offsets[rows + 1] - starts
-            cols = np.arange(self.S)[None, :]
+            cols = np.arange(len(m_bits))[None, :]
             idx = starts[:, None] + np.minimum(cols, np.maximum(lens[:, None] - 1, 0))
             idx = np.minimum(idx, max(codes.size - 1, 0))
             dense = np.where(cols < lens[:, None], np.asarray(codes)[idx], 0).astype(
                 np.int32
             )
-            tables, m_bits = self.pallas_tables()
             out = delayed_decode(jnp.asarray(dense), tables, m_bits)
         # Wait: the device's time left after dispatch plus the copy back.
         with telemetry.span("repro.decode.wait"):
-            syms = np.asarray(out).astype(np.int64)[:n]
+            syms = np.asarray(out).astype(np.int64)[:n, :self.S]
         _C_KERNEL_ROWS.add(n)
         _C_KERNEL_ROWS_PADDED.add(padded)
         return syms
 
-    def pallas_tables(self) -> Tuple[Any, int]:
-        """Lazy ``(tables f32[R, 7 * 128], m_bits)`` in the kernel's layout."""
+    def pallas_tables(self) -> Tuple[Any, Tuple[int, ...]]:
+        """Lazy ``(tables f32[R, 7 * 128], m_bits)`` in the kernel's layout,
+        at the family's slot bits (:meth:`kernel_m_bits`)."""
         if self._tables is None:
             with telemetry.span("repro.plan.pallas_pack"):
                 from repro.kernels.ops import pack_slot_tables
-                self._tables, self._m_bits = pack_slot_tables(self.coders)
+                self._tables, self._m_bits = pack_slot_tables(
+                    self.coders, self.family[0].kernel_m_bits()
+                )
                 _C_PALLAS_PACK.inc()
         return self._tables, self._m_bits
+
+    def kernel_m_bits(self) -> Optional[Tuple[int, ...]]:
+        """Per-slot bucket bits every family member packs its kernel tables
+        at: the widest member's at each slot, over the longest member's
+        slots, so the whole family shares one compiled kernel per row
+        bucket.  ``None`` for a family of one (its own bits)."""
+        if len(self.family) == 1:
+            return None
+        if self._family_bits is None:
+            from repro.kernels.ops import coder_m_bits
+            per = [[coder_m_bits(c) for c in m.coders] for m in self.family]
+            width = max(len(p) for p in per)
+            self._family_bits = tuple(
+                max(p[s] for p in per if s < len(p)) for s in range(width)
+            )
+        return self._family_bits
 
     def decode_syms_to_rows(
         self, syms: np.ndarray, columns: Optional[Sequence[str]] = None
@@ -996,25 +1184,28 @@ def decode_select_prefix(
     )
 
 
-def compile_plan(codec) -> TablePlan:
-    """Lower a fitted TableCodec to a TablePlan, or raise PlanFallback."""
-    if codec.block_tuples != 1:
-        raise PlanFallback(
-            f"block_tuples={codec.block_tuples}: multi-tuple blocks chain "
-            "virtual bits across rows")
+def _lower(
+    codec, choice: Dict[str, Any], shared: Dict[str, Any]
+) -> List[Tuple[str, Any, int]]:
+    """One plan's lowerings; string columns take their lowering from
+    ``choice``, every other column lowers as its model says.  Lowerings
+    that do not depend on slot offsets are kept in ``shared`` for the
+    family's other members."""
     lowerings: List[Tuple[str, Any, int]] = []
     plan_of: Dict[str, Tuple[Any, int]] = {}
     offset = 0
     for name in codec.order:
         m = codec.models[name]
-        if isinstance(m, ConditionalCategoricalModel):
-            cp: Any = _build_cond(m, plan_of, name)
+        if name in choice:
+            cp: Any = choice[name]
+        elif name in shared:
+            cp = shared[name]
+        elif isinstance(m, ConditionalCategoricalModel):
+            cp = _build_cond(m, plan_of, name)  # keyed on parent slot offsets
         elif isinstance(m, CategoricalModel):
-            cp = _CatPlan(m)
+            cp = shared[name] = _CatPlan(m)
         elif isinstance(m, NumericModel):
-            cp = _NumPlan(m)
-        elif isinstance(m, StringModel):
-            cp = _StrPlan(m)
+            cp = shared[name] = _NumPlan(m)
         elif isinstance(m, TimeSeriesModel):
             raise PlanFallback(
                 f"column {name!r}: time-series model is stateful across rows"
@@ -1026,4 +1217,49 @@ def compile_plan(codec) -> TablePlan:
         lowerings.append((name, cp, offset))
         plan_of[name] = (cp, offset)
         offset += cp.n_slots
-    return TablePlan(codec, lowerings)
+    return lowerings
+
+
+def _family_choices(
+    options: Dict[str, List[Tuple[Any, float]]]
+) -> List[Dict[str, Any]]:
+    """The family's string lowerings: every column's first template, then
+    the cross product's other combinations in descending joint share (a
+    beam of ``MAX_FAMILY`` suffices: a prefix outside the best ``K``
+    prefixes cannot start one of the best ``K`` products), ``MAX_FAMILY``
+    in all."""
+    names = list(options)
+    beam: List[Tuple[float, Tuple[int, ...]]] = [(1.0, ())]
+    for name in names:
+        beam = sorted(
+            ((sc * options[name][i][1], key + (i,))
+             for (sc, key), i in itertools.product(beam, range(len(options[name])))),
+            key=lambda e: (-e[0], e[1]),
+        )[:MAX_FAMILY]
+    first = (0,) * len(names)
+    keys = [first] + [key for _, key in beam if key != first][:MAX_FAMILY - 1]
+    return [{n: options[n][i][0] for n, i in zip(names, key)} for key in keys]
+
+
+def compile_plan(codec) -> TablePlan:
+    """Lower a fitted TableCodec to its plan family, or raise PlanFallback.
+
+    Returns the family's first member, the modal plan; a table whose string
+    columns each have one template compiles to a family of one.
+    """
+    if codec.block_tuples != 1:
+        raise PlanFallback(
+            f"block_tuples={codec.block_tuples}: multi-tuple blocks chain "
+            "virtual bits across rows")
+    options = {
+        name: _column_templates(codec.models[name])
+        for name in codec.order
+        if isinstance(codec.models[name], StringModel)
+    }
+    shared: Dict[str, Any] = {}
+    family = [TablePlan(codec, _lower(codec, choice, shared))
+              for choice in _family_choices(options)]
+    for t, plan in enumerate(family):
+        plan.family = family
+        plan.template = t
+    return family[0]

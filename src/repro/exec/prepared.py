@@ -11,7 +11,8 @@ bucket, backend)``:
 * **batch bucket** — the pow2-padded batch size, aligning the entry with
   the jit/trace cache of the Pallas decode kernel underneath;
 * **backend** — the requested decode backend, because lowering for
-  ``"pallas"`` additionally packs the plan's slot tables.
+  ``"pallas"`` additionally packs the slot tables of every member of
+  the plan family.
 
 A hit replays cached artifacts — warmed codec plans, the vectorized key
 router, packed kernel tables — with no per-call re-lowering.  A miss
@@ -110,7 +111,8 @@ class PreparedOp:
             if backend == "pallas":
                 for plan in plans:
                     if plan is not None and plan.pallas_ok:
-                        plan.pallas_tables()
+                        for member in plan.family:
+                            member.pallas_tables()
             low = _Lowered(epoch, len(table.schema.primary_key), table.n_shards)
             self._cache[slot] = low
         return low

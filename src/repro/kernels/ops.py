@@ -8,7 +8,7 @@ table layout.
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -16,7 +16,7 @@ import numpy as np
 from repro.core.coders import DiscreteCoder, UniformCoder
 from . import ref as ref_lib
 from .alias_decode import alias_decode
-from .delayed_decode import LANES, N_FIELDS, delayed_decode, slot_layout
+from .delayed_decode import LANES, N_FIELDS, TOTAL_BITS, delayed_decode, slot_layout
 from .flash_prefill import flash_prefill_attention
 from .kv_attention import kv_attention_int8
 
@@ -30,7 +30,39 @@ __all__ = [
 ]
 
 
-def pack_slot_tables(coders: Sequence) -> Tuple[jnp.ndarray, Tuple[int, ...]]:
+def coder_m_bits(coder) -> int:
+    """Bucket bits of a slot coder's kernel table (its own width)."""
+    if isinstance(coder, DiscreteCoder):
+        return int(coder.tables.m_bits)
+    if isinstance(coder, UniformCoder):
+        return ref_lib.uniform_m_bits(coder.G)
+    raise TypeError(f"cannot pack device tables for {type(coder).__name__}")
+
+
+def widen_table(tab: np.ndarray, m: int, m_to: int) -> np.ndarray:
+    """A slot's ``[2**m, 7]`` decode table re-bucketed at ``2**m_to``
+    buckets (``m_to >= m``).
+
+    Each bucket splits into ``2**(m_to - m)`` sub-buckets of width ``w``
+    that keep its symbols, offsets and option counts; sub-bucket ``j``
+    reads the low bits past ``j * w``, so its threshold moves down by
+    ``j * w``, clipped to ``[0, w]``.  Every code decodes as before.
+    """
+    d = m_to - m
+    if d < 0:
+        raise ValueError(f"cannot narrow a {m}-bit table to {m_to} bits")
+    if d == 0:
+        return tab
+    w = 1 << (TOTAL_BITS - m_to)
+    out = np.repeat(tab, 1 << d, axis=0)
+    thr = tab[:, :1] - w * np.arange(1 << d, dtype=np.float32)[None, :]
+    out[:, 0] = np.clip(thr, 0, w).reshape(-1)
+    return out
+
+
+def pack_slot_tables(
+    coders: Sequence, m_bits: Optional[Sequence[int]] = None
+) -> Tuple[jnp.ndarray, Tuple[int, ...]]:
     """Pack per-slot decode tables into the kernel's [R, 7 * 128] layout.
 
     Accepts a mix of :class:`DiscreteCoder` (alias layout, Appendix C) and
@@ -39,6 +71,11 @@ def pack_slot_tables(coders: Sequence) -> Tuple[jnp.ndarray, Tuple[int, ...]]:
     delayed-decode kernel consumes.  Each slot keeps its own bucket count:
     bucket ``p`` of slot ``s`` lands at row ``offset_s + (p >> 7)``, lane
     ``field * 128 + (p & 127)`` (:func:`~.delayed_decode.slot_layout`).
+
+    ``m_bits`` packs at given slot widths instead: each coder's table is
+    widened to its slot's bits (:func:`widen_table`), and slots past the
+    last coder hold zeros (their symbols are never read).  Plans that pack
+    at the same ``m_bits`` share one compiled kernel.
     """
     tabs: List[np.ndarray] = []
     mbits: List[int] = []
@@ -51,6 +88,13 @@ def pack_slot_tables(coders: Sequence) -> Tuple[jnp.ndarray, Tuple[int, ...]]:
             raise TypeError(f"cannot pack device tables for {type(c).__name__}")
         tabs.append(np.asarray(t))
         mbits.append(m)
+    if m_bits is not None:
+        if len(m_bits) < len(tabs):
+            raise ValueError(f"{len(m_bits)} slot widths for {len(tabs)} coders")
+        tabs = [widen_table(t, m, int(mt)) for t, m, mt in zip(tabs, mbits, m_bits)]
+        tabs += [np.zeros((1 << int(mt), N_FIELDS), np.float32)
+                 for mt in m_bits[len(mbits):]]
+        mbits = [int(mt) for mt in m_bits]
     layout, n_rows = slot_layout(tuple(mbits))
     out = np.zeros((n_rows, N_FIELDS, LANES), np.float32)
     for t, (off, _) in zip(tabs, layout):

@@ -34,6 +34,11 @@ def pack_tables(coder) -> Tuple[jnp.ndarray, int]:
     return jnp.asarray(tab), int(t.m_bits)
 
 
+def uniform_m_bits(G: int) -> int:
+    """Bucket bits of a G-ary uniform coder's table: ``ceil(log2 G)``."""
+    return (int(G) - 1).bit_length() if G > 1 else 0
+
+
 def pack_tables_uniform(coder) -> Tuple[jnp.ndarray, int]:
     """Bucket-major decode table of a UniformCoder in the same [M, 7] layout.
 
@@ -46,7 +51,7 @@ def pack_tables_uniform(coder) -> Tuple[jnp.ndarray, int]:
     """
     import numpy as np
     G = int(coder.G)
-    m = max(0, int(np.ceil(np.log2(G)))) if G > 1 else 0
+    m = uniform_m_bits(G)
     M = 1 << m
     W = TOTAL >> m
     tab = np.zeros((M, 7), np.float32)

@@ -14,8 +14,8 @@ non-matching rows:
    Spilled blocks are read through (CRC-checked) without promotion, so an
    OLAP scan never evicts the OLTP hot set.
 3. **Materialize survivors** — matching rows gather into one compact CSR
-   and decode with ONE ``decode_select`` per plan version, reconstructing
-   only the projected columns.
+   and decode with ONE ``decode_select`` per plan version and plan-family
+   member, reconstructing only the projected columns.
 
 Slow blocks, non-lowerable versions, and pending rows fall back to
 decode-then-filter with the same value-space matchers, so results are
@@ -427,17 +427,23 @@ def _scan_table(
     cache: Dict[int, np.ndarray] = {}
     if live.size:
         fastm = table._fast[blks]
-        vers = table._plan_ver[blks]
+        # one group per (plan version, plan-family member) of fast blocks
+        route = table._plan_ver[blks].astype(np.int64) << 8 | table._tmpl[blks]
         scalar = ~fastm  # slow blocks always decode under their own codec
-        for v in np.unique(vers[fastm]):
-            sel = fastm & (vers == v)
+        versions = set()
+        for g in np.unique(route[fastm]).tolist():
+            sel = fastm & (route == g)
             ids_v, blks_v = live[sel], blks[sel]
-            codec_v = table._codecs[v]
+            codec_v = table._codecs[g >> 8]
             plan = codec_v.compile()
+            if plan is not None:
+                plan = plan.family[g & 0xFF]
             lowered = _lower_preds(plan, preds) if plan is not None else _FALLBACK
             if lowered is _IMPOSSIBLE:
                 continue  # fast => conforming => provably no match
-            stats.versions += 1
+            if g >> 8 not in versions:
+                versions.add(g >> 8)
+                stats.versions += 1
             if lowered is _FALLBACK:
                 survivors = np.arange(ids_v.size)
                 stats.blocks_fallback += int(ids_v.size)

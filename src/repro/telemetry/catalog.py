@@ -20,6 +20,7 @@ METRICS: Tuple[str, ...] = (
     "repro.core.decode",
     "repro.core.decode.rows",
     "repro.core.decode.scalar_block",
+    "repro.core.decode.scalar_rows",
     "repro.core.encode",
     "repro.core.encode.rows",
     "repro.core.encode.scalar",
@@ -40,6 +41,7 @@ METRICS: Tuple[str, ...] = (
     "repro.plan.pallas_downgrade",
     "repro.plan.pallas_pack",
     "repro.plan.pallas_pack.events",
+    "repro.plan.template_rows",
     # -- residency / out-of-core tier ------------------------------------
     "repro.residency.fault_in",
     "repro.residency.fault_in.blocks",

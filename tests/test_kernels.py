@@ -86,22 +86,27 @@ class TestDelayedDecodeTPUInterpret:
         from repro.kernels import delayed_decode as dd
 
         t = tpcc_db[name].shards[0].table
-        plan = t.codec.compile()
-        rows = np.nonzero(t._fast[:t.n_blocks])[0][:256]
-        assert rows.size
+        fast = t._fast[:t.n_blocks]
         codes = t.arena[:t.used].astype(np.int64)
-        want = plan.decode_select(t.arena[:t.used], t.block_offsets, rows,
-                                  backend="numpy")
-        sel = np.concatenate([rows, np.full(256 - rows.size, rows[-1])])
-        starts, ends = t.block_offsets[sel], t.block_offsets[sel + 1]
-        runs = np.concatenate([codes[a:b] for a, b in zip(starts, ends)])
-        dense = ops.dense_codes(
-            runs, np.concatenate([[0], np.cumsum(ends - starts)]), plan.S)
-        tables, m_bits = plan.pallas_tables()
-        with pltpu.force_tpu_interpret_mode():
-            got = dd._delayed_decode_jit(jnp.asarray(dense), tables, m_bits,
-                                         False)
-        np.testing.assert_array_equal(np.asarray(got)[:rows.size], want)
+        # every member of the plan family, each on the blocks it encoded,
+        # at the family's shared kernel layout
+        for plan in t.codec.compile().family:
+            rows = np.nonzero(fast & (t.block_templates == plan.template))[0][:256]
+            assert rows.size
+            want = plan.decode_select(t.arena[:t.used], t.block_offsets, rows,
+                                      backend="numpy")
+            sel = np.concatenate([rows, np.full(256 - rows.size, rows[-1])])
+            starts, ends = t.block_offsets[sel], t.block_offsets[sel + 1]
+            runs = np.concatenate([codes[a:b] for a, b in zip(starts, ends)])
+            tables, m_bits = plan.pallas_tables()
+            dense = ops.dense_codes(
+                runs, np.concatenate([[0], np.cumsum(ends - starts)]),
+                len(m_bits))
+            with pltpu.force_tpu_interpret_mode():
+                got = dd._delayed_decode_jit(jnp.asarray(dense), tables,
+                                             m_bits, False)
+            np.testing.assert_array_equal(
+                np.asarray(got)[:rows.size, :plan.S], want)
 
 
 class TestKVAttentionKernel:
