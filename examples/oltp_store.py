@@ -227,7 +227,7 @@ def durable(n_rows=3000, n_ops=800):
     try:
         schema, gen = tpcc.TABLES["customer"]
         rows = gen(n_rows)
-        db = Database(backend="blitzcrank", memory_budget=64 * 1024,
+        db = Database(backend="blitzcrank", memory_limit=64 * 1024,
                       durability=root)
         table = db.create_table(TableSchema("customer", schema, "c_id"),
                                 sample_rows=rows[: n_rows // 2])

@@ -448,9 +448,7 @@ class ResidencyManager:
         config: Optional[ResidencyConfig] = None,
         io: Optional[Any] = None,
     ):
-        if budget_bytes <= 0:
-            raise ValueError("memory_budget must be positive")
-        self.budget = int(budget_bytes)
+        self.budget = budget_bytes
         self.config = config or ResidencyConfig()
         self.disk = DiskArena(spill_path, io=io)
         self.hand = 0
@@ -469,6 +467,19 @@ class ResidencyManager:
             self.disk.close()
 
     # -- budget arithmetic ----------------------------------------------
+    @property
+    def budget(self) -> int:
+        """Resident live code bytes the owning store may hold."""
+        return self._budget
+
+    @budget.setter
+    def budget(self, budget_bytes: int) -> None:
+        # Movable on a live store (a database limit re-split); the owner
+        # spills down to a lowered budget at its next enforcement.
+        if budget_bytes <= 0:
+            raise ValueError("memory_budget must be positive")
+        self._budget = int(budget_bytes)
+
     @property
     def budget_codes(self) -> int:
         """The budget expressed in uint16 code units."""

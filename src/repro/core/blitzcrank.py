@@ -54,6 +54,8 @@ _C_PLAN_HIT = telemetry.counter("repro.plan.cache.hit")
 _C_PLAN_MISS = telemetry.counter("repro.plan.cache.miss")
 _C_SPILL_BLOCKS = telemetry.counter("repro.residency.spill.blocks")
 _C_FAULT_BLOCKS = telemetry.counter("repro.residency.fault_in.blocks")
+_C_SPILL_BYTES = telemetry.counter("repro.residency.spill.bytes")
+_C_FAULT_BYTES = telemetry.counter("repro.residency.fault_in.bytes")
 _C_MIGRATED = telemetry.counter("repro.store.migrate.rows")
 _C_PALLAS_DOWNGRADE = telemetry.counter("repro.plan.pallas_downgrade")
 _C_SCALAR_ROWS = telemetry.counter("repro.core.decode.scalar_rows")
@@ -643,6 +645,16 @@ class CompressedTable:
         """Compressed payload bytes currently living on disk (not memory)."""
         return 2 * self._spilled_codes
 
+    @property
+    def resident_code_bytes(self) -> int:
+        """Live code bytes held in memory: what the budget bounds."""
+        return 2 * (self.used - self._dead_codes)
+
+    @property
+    def code_bytes(self) -> int:
+        """Live code bytes, resident or spilled: the table's data size."""
+        return 2 * (self.used - self._dead_codes + self._spilled_codes)
+
     def set_memory_budget(
         self,
         budget: int,
@@ -650,17 +662,24 @@ class CompressedTable:
         config: Optional[ResidencyConfig] = None,
         spill_io: Optional[Any] = None,
     ) -> None:
-        """Install a residency manager bounding live resident code bytes.
+        """Bound live resident code bytes, installing the cold tier on the
+        first call and moving its budget on later ones.
 
         Single-tuple granularity only (the spill unit is the block and
         fault-in re-points rows at freshly appended blocks, which needs
         the mutation machinery).  Can be enabled at any point in the
         table's life; existing blocks start resident-and-referenced and
-        the first enforcement sweeps them against the budget.
+        the first enforcement sweeps them against the budget.  On a table
+        that has a cold tier only the budget moves (the spill file and
+        policy stay), and a lowered budget spills down to it here.
         """
         self._require_mutable("set_memory_budget")
         if self._res is not None:
-            raise ValueError("memory budget already set")
+            if spill_path is not None or config is not None or spill_io is not None:
+                raise ValueError("the cold tier is installed: only its budget moves")
+            self._res.budget = budget
+            self._enforce_budget()
+            return
         self.flush()
         self._res = ResidencyManager(budget, spill_path, config, io=spill_io)
         cap = self._offsets.size - 1
@@ -787,6 +806,7 @@ class CompressedTable:
             self._spilled_codes += total
             res.spills += int(blocks.size)
             _C_SPILL_BLOCKS.add(int(blocks.size))
+            _C_SPILL_BYTES.add(2 * total)
         self.sanitize_boundary("spill_blocks")
 
     def _fault_in(self, blocks: np.ndarray) -> None:
@@ -841,6 +861,7 @@ class CompressedTable:
             res.faults += n
             res.fault_batches += 1
             _C_FAULT_BLOCKS.add(n)
+            _C_FAULT_BYTES.add(2 * total)
         self.sanitize_boundary("fault_in")
 
     def _maybe_compact_disk(self) -> None:

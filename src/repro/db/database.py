@@ -6,6 +6,10 @@ resulting :class:`~repro.db.Table` s.  It carries engine-wide defaults
 can override, and aggregates ``stats()`` / ``nbytes`` across every table
 and shard — the number the paper's §6 "whole-database memory reduction"
 claim is about, and the one ``benchmarks/bench_db_tpcc.py`` reports.
+
+``memory_limit`` caps the resident compressed code bytes of the whole
+database (DESIGN.md §6.2): the database splits it among its tables by
+their live code bytes and re-splits as they grow or shrink.
 """
 
 from __future__ import annotations
@@ -22,6 +26,13 @@ from repro import telemetry
 from .schema import TableSchema
 from .table import StoreFactory, Table
 
+_C_SPLITS = telemetry.counter("repro.residency.split.count")
+
+# A limited database re-splits its memory at the end of a write verb once
+# some table's live code bytes moved by more than this share of what they
+# were at the last split (a table that had none moves by any write).
+SPLIT_DRIFT = 1 / 16
+
 
 class Database:
     """Catalog of tables sharing engine-wide defaults.
@@ -36,17 +47,24 @@ class Database:
         backend: str | StoreFactory = "blitzcrank",
         n_shards: int = 1,
         store_kwargs: Optional[Dict[str, Any]] = None,
-        memory_budget: Optional[int] = None,
+        memory_limit: Optional[int] = None,
         durability: Optional[Any] = None,
     ):
         self.backend = backend
         self.n_shards = int(n_shards)
         self.store_kwargs = dict(store_kwargs or {})
-        # Engine-wide default *per-table* memory budget (DESIGN.md §6);
-        # each table splits its budget across its shards.  Table sizes
-        # are not knowable at catalog time, so a proportional split is
-        # the loader's job (see bench_out_of_core's per-table budgets).
-        self.memory_budget = int(memory_budget) if memory_budget is not None else None
+        # Resident code bytes the whole database may hold (DESIGN.md §6.2).
+        # Tables given their own ``memory_budget`` keep it and count
+        # against the limit; the rest share what is left by their live
+        # code bytes (split_limit), each table's part divided over its
+        # shards.  ``_split_at`` holds each sharing table's code bytes at
+        # the last split, against which a write's drift is measured.
+        if memory_limit is not None and memory_limit <= 0:
+            raise ValueError("memory_limit must be positive")
+        if memory_limit is not None and "memory_budget" in self.store_kwargs:
+            raise ValueError("memory_limit and a per-shard memory_budget exclude each other")
+        self.memory_limit = int(memory_limit) if memory_limit is not None else None
+        self._split_at: Dict[str, int] = {}
         self._tables: Dict[str, Table] = {}
         # Durability (DESIGN.md §7): a DurabilityConfig (or just its root
         # path) turns on one WAL per table + checkpoints; ``None`` keeps
@@ -86,6 +104,15 @@ class Database:
             raise ValueError(f"table {schema.name!r} already registered")
         kwargs = dict(self.store_kwargs)
         kwargs.update(store_kwargs or {})
+        if self.memory_limit is not None:
+            if "memory_budget" in kwargs:
+                raise ValueError(
+                    "memory_limit and a per-shard memory_budget exclude each other")
+            if (memory_budget is not None
+                    and self._fixed_budgets() + memory_budget > self.memory_limit):
+                raise ValueError(
+                    f"table budgets {self._fixed_budgets() + memory_budget} exceed "
+                    f"memory_limit {self.memory_limit}")
         if self._dur is not None:
             # fault injection (and crash points) must cover spill I/O too
             kwargs.setdefault("spill_io", self._io)
@@ -95,13 +122,12 @@ class Database:
             n_shards=self.n_shards if n_shards is None else n_shards,
             sample_rows=sample_rows,
             store_kwargs=kwargs,
-            memory_budget=(
-                self.memory_budget if memory_budget is None else memory_budget
-            ),
+            memory_budget=memory_budget,
         )
         self._tables[schema.name] = table
         if self._dur is not None:
             self._attach_durability(table, sample_rows)
+        self._join_limit(table)
         return table
 
     def drop_table(self, name: str) -> None:
@@ -111,6 +137,7 @@ class Database:
             raise KeyError(f"no table {name!r}")
         table = self._tables.pop(name)
         table.close(unlink=True)
+        self.split_limit()
         if self._dur is not None:
             self.checkpoint()
 
@@ -210,6 +237,52 @@ class Database:
         self._note_ops(0)  # honor a checkpoint request from the steps
         return out
 
+    # -- the memory limit (DESIGN.md §6.2) --------------------------------
+    def _join_limit(self, table: Table) -> None:
+        """Bring a new or adopted table under the limit: a table with its
+        own budget counts against it, the others join the split."""
+        if self.memory_limit is None:
+            return
+        if self._dur is None:
+            table.on_ops(self._note_ops)
+        self.split_limit()
+
+    def _fixed_budgets(self) -> int:
+        return sum(t.memory_budget for t in self._tables.values()
+                   if t.memory_budget is not None)
+
+    def split_limit(self) -> Dict[str, int]:
+        """Re-split the limit now: each table without a budget of its own
+        gets what the fixed budgets leave, times its share of those
+        tables' live code bytes (evenly while none has any; at least one
+        byte).  A table whose part fell spills down to it before this
+        returns.  Returns ``{table: budget}``."""
+        if self.memory_limit is None:
+            return {}
+        with telemetry.span("repro.residency.split"):
+            sizes = {n: t.code_bytes for n, t in self._tables.items()
+                     if t.memory_budget is None}
+            free = self.memory_limit - self._fixed_budgets()
+            total = sum(sizes.values())
+            budgets = {n: max(1, free * b // total if total else free // len(sizes))
+                       for n, b in sizes.items()}
+            for n, b in budgets.items():
+                self._tables[n].set_memory_budget(b)
+            self._split_at = sizes
+            _C_SPLITS.inc()
+        return budgets
+
+    def _maybe_split(self) -> None:
+        """Re-split once some sharing table's code bytes drifted past
+        :data:`SPLIT_DRIFT` of their size at the last split."""
+        for n, t in self._tables.items():
+            if t.memory_budget is not None:
+                continue
+            then = self._split_at.get(n)
+            if then is None or abs(t.code_bytes - then) > SPLIT_DRIFT * then:
+                self.split_limit()
+                return
+
     # -- recovery entry points (DESIGN.md §7) -----------------------------
     # The recovery module drives engine-private catalog and checkpoint
     # state through these instead of reaching into ``_tables`` /
@@ -223,6 +296,7 @@ class Database:
         table.on_shards_built(self._wire_maintenance)
         if table.shards:
             self._wire_maintenance(table)
+        self._join_limit(table)
 
     def discard_table(self, name: str) -> None:
         """Drop ``name`` from the catalog without closing its files
@@ -296,6 +370,8 @@ class Database:
                 maint.on_step.append(request)
 
     def _note_ops(self, n: int) -> None:
+        if self.memory_limit is not None:
+            self._maybe_split()
         if self._dur is None or self._recovering:
             return
         self._ops_since_ckpt += int(n)
@@ -328,7 +404,7 @@ class Database:
                     for k, v in self.store_kwargs.items()
                     if k not in ("codec", "spill_io")
                 },
-                "memory_budget": self.memory_budget,
+                "memory_limit": self.memory_limit,
             },
             "tables": tables,
         }
@@ -360,6 +436,16 @@ class Database:
     def n_live(self) -> int:
         return sum(t.n_live for t in self._tables.values())
 
+    @property
+    def code_bytes(self) -> int:
+        """Live compressed code bytes of every table, resident or spilled."""
+        return sum(t.code_bytes for t in self._tables.values())
+
+    @property
+    def resident_code_bytes(self) -> int:
+        """Live compressed code bytes held in memory: what the limit caps."""
+        return sum(t.resident_code_bytes for t in self._tables.values())
+
     def stats(self) -> Dict[str, Any]:
         per_table = {n: t.stats() for n, t in sorted(self._tables.items())}
         out = {
@@ -377,6 +463,7 @@ class Database:
             # memory, spilled bytes live on disk and are summed separately
             out["spilled_bytes"] = sum(r["spilled_bytes"] for r in res)
             out["residency"] = {
+                "memory_limit": self.memory_limit,
                 "budget_bytes": sum(r["budget_bytes"] for r in res),
                 "spilled_bytes": out["spilled_bytes"],
                 "spills": sum(r["spills"] for r in res),

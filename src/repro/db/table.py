@@ -84,16 +84,15 @@ class Table:
         self.n_shards = int(n_shards)
         self.backend = backend
         self.store_kwargs = dict(store_kwargs or {})
-        # Out-of-core budget (DESIGN.md §6): a table-level budget is split
-        # evenly across the hash-partitioned shards — placement is a
-        # uniform hash of the key, so each shard carries ~1/N of the data
-        # and deserves ~1/N of the memory.  An explicit per-shard
-        # ``memory_budget`` in store_kwargs wins over the split.
+        # Out-of-core budget (DESIGN.md §6.2): ``memory_budget`` is the
+        # table's own budget; a limited Database instead moves the table's
+        # share of its limit in through set_memory_budget (``budget``).
+        # Either is split evenly across the hash-partitioned shards —
+        # placement is a uniform hash of the key, so each shard carries
+        # ~1/N of the data and deserves ~1/N of the memory.  An explicit
+        # per-shard ``memory_budget`` in store_kwargs wins over the split.
         self.memory_budget = int(memory_budget) if memory_budget is not None else None
-        if self.memory_budget is not None and "memory_budget" not in self.store_kwargs:
-            self.store_kwargs["memory_budget"] = max(
-                1, self.memory_budget // self.n_shards
-            )
+        self.budget = self.memory_budget
         self._shards: List[RowStore] = []
         self._dir: Dict[Key, Tuple[int, int]] = {}
         self._prepared: Dict[str, Any] = {}
@@ -126,6 +125,8 @@ class Table:
         except (TypeError, ValueError):  # e.g. builtins without signatures
             can_share = False
         kwargs = dict(self.store_kwargs)
+        if self.budget is not None:
+            kwargs.setdefault("memory_budget", self._shard_budget())
         spill_base = kwargs.get("spill_path")
         for j in range(self.n_shards):
             if spill_base is not None and self.n_shards > 1:
@@ -161,6 +162,16 @@ class Table:
             self._install_repair_fns()
         if self._on_shards_built is not None:
             self._on_shards_built(self)
+
+    def _shard_budget(self) -> int:
+        return max(1, self.budget // self.n_shards)
+
+    def set_memory_budget(self, budget: int) -> None:
+        """Move the table's budget on a live table: each shard gets its
+        even part, and a shard whose part fell spills down to it now."""
+        self.budget = int(budget)
+        for shard in self._shards:
+            shard.set_memory_budget(self._shard_budget())
 
     def on_shards_built(
         self, callback: Optional[Callable[["Table"], None]]
@@ -600,6 +611,12 @@ class Table:
         if self._shards:
             self._install_repair_fns()
 
+    def on_ops(self, callback: Optional[Callable[[int], None]]) -> None:
+        """Designated entry point for the owning engine to hear the row
+        count at the end of every write verb (a limited database re-splits
+        its memory there) without a WAL."""
+        self._on_ops = callback
+
     def _log(self, op: str, payload: Any) -> None:
         if self._wal is not None:
             self._wal.log(op, payload)
@@ -681,6 +698,7 @@ class Table:
             "n_shards": self.n_shards,
             "store_kwargs": self.clean_store_kwargs(),
             "memory_budget": self.memory_budget,
+            "budget": self.budget,
             "dir": dict(self._dir),
             "shards": (
                 [s.snapshot_state() for s in self._shards] if self._shards else None
@@ -700,6 +718,7 @@ class Table:
         if spill_io is not None:
             self.store_kwargs["spill_io"] = spill_io
         self.memory_budget = state["memory_budget"]
+        self.budget = state.get("budget", self.memory_budget)
         self._dir = dict(state["dir"])
         self._shards = []
         self._prepared = {}
@@ -739,6 +758,16 @@ class Table:
     @property
     def index_bytes(self) -> int:
         return INDEX_ENTRY_OVERHEAD * len(self._dir)
+
+    @property
+    def code_bytes(self) -> int:
+        """Every shard's live code bytes, resident or spilled."""
+        return sum(s.code_bytes for s in self._shards)
+
+    @property
+    def resident_code_bytes(self) -> int:
+        """Every shard's live code bytes held in memory."""
+        return sum(s.resident_code_bytes for s in self._shards)
 
     @property
     def nbytes(self) -> int:

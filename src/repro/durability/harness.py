@@ -97,10 +97,10 @@ def _apply(table, schema: TableSchema, op: str, payload: Any) -> None:
 def _reference_state(backend: str, rows: List[Dict[str, Any]],
                      schema: TableSchema, n_batches: int,
                      store_kwargs: Optional[Dict[str, Any]],
-                     memory_budget: Optional[int]) -> Database:
+                     memory_limit: Optional[int]) -> Database:
     """A fresh non-durable database that applied the expected prefix."""
     db = Database(backend=backend, store_kwargs=dict(store_kwargs or {}),
-                  memory_budget=memory_budget)
+                  memory_limit=memory_limit)
     t = db.create_table(schema, sample_rows=rows[:N_POP])
     t.insert_many(rows[:N_POP])
     for op, payload in _batches(rows, schema)[:n_batches]:
@@ -133,7 +133,7 @@ def _compare(recovered: Database, reference: Database,
 
 def run_crash_scenario(point: str, backend: str = "blitzcrank",
                        seed: int = 0, checkpoint_every: int = 7,
-                       memory_budget: Optional[int] = None,
+                       memory_limit: Optional[int] = None,
                        ) -> Dict[str, Any]:
     """Kill at ``point``, recover, verify.  Returns a result dict with
     ``ok`` (bit-identical), ``crashed`` (the point actually fired), and
@@ -143,18 +143,18 @@ def run_crash_scenario(point: str, backend: str = "blitzcrank",
     rows = tpcc.gen_customer(N_ROWS, seed=seed)
     schema = _schema()
     store_kwargs: Dict[str, Any] = {}
-    if memory_budget is None:
+    if memory_limit is None:
         # tight enough that the 256-row population spills under either
         # representation (~8 KB compressed arena, ~10x that raw)
-        memory_budget = {"blitzcrank": 4 * 1024, "silo": 24 * 1024}.get(
+        memory_limit = {"blitzcrank": 4 * 1024, "silo": 24 * 1024}.get(
             backend)
-    budget = memory_budget if backend in ("blitzcrank", "silo") else None
+    budget = memory_limit if backend in ("blitzcrank", "silo") else None
     inj = FaultInjector(seed)
     root = tempfile.mkdtemp(prefix="blitz-crash-")
     try:
         cfg = DurabilityConfig(root=root, fsync_every=1, io=DurableIO(inj))
         db = Database(backend=backend, store_kwargs=dict(store_kwargs),
-                      memory_budget=budget, durability=cfg)
+                      memory_limit=budget, durability=cfg)
         t = db.create_table(schema, sample_rows=rows[:N_POP])
         t.insert_many(rows[:N_POP])
         db.checkpoint()
@@ -242,7 +242,7 @@ def _durable_customer_db(
 ) -> Tuple[Database, Any, TableSchema]:
     schema = _schema()
     cfg = DurabilityConfig(root=root, fsync_every=1, io=io)
-    db = Database(backend="blitzcrank", memory_budget=4 * 1024,
+    db = Database(backend="blitzcrank", memory_limit=4 * 1024,
                   durability=cfg)
     t = db.create_table(schema, sample_rows=rows[:N_POP])
     t.insert_many(rows[:N_POP])

@@ -99,7 +99,7 @@ def open_database(
         backend=engine.get("backend") or "blitzcrank",
         n_shards=engine.get("n_shards", 1),
         store_kwargs=engine.get("store_kwargs") or {},
-        memory_budget=engine.get("memory_budget"),
+        memory_limit=engine.get("memory_limit"),
         durability=cfg,
     )
     with db.recovery_mode():

@@ -57,6 +57,8 @@ _C_MERGES = telemetry.counter("repro.store.merge.events")
 _C_OVERLAY_HITS = telemetry.counter("repro.store.overlay.hits")
 _C_ROW_FAULTS = telemetry.counter("repro.residency.fault_in.rows.count")
 _C_ROW_SPILLS = telemetry.counter("repro.residency.spill.rows.count")
+_C_SPILL_BYTES = telemetry.counter("repro.residency.spill.bytes")
+_C_FAULT_BYTES = telemetry.counter("repro.residency.fault_in.bytes")
 
 
 class RowStore:
@@ -259,8 +261,9 @@ class _BytesRowStore(RowStore):
         # SpillCorruptionError (never as garbage rows).
         self.repair_fn: Optional[Callable] = None
         self.repairs = 0
+        self._spill_path, self._spill_io = spill_path, spill_io
         if memory_budget is not None:
-            self._res = ResidencyManager(memory_budget, spill_path, io=spill_io)
+            self.set_memory_budget(memory_budget)
 
     def is_live(self, i: int) -> bool:
         i = int(i)
@@ -277,6 +280,33 @@ class _BytesRowStore(RowStore):
         raise NotImplementedError
 
     # -- cold tier -------------------------------------------------------
+    @property
+    def memory_budget(self) -> Optional[int]:
+        return self._res.budget if self._res is not None else None
+
+    def set_memory_budget(self, budget: int) -> None:
+        """Install the cold tier, or move its budget on a live store (a
+        lowered budget spills down to it here)."""
+        if self._res is None:
+            self._res = ResidencyManager(budget, self._spill_path, io=self._spill_io)
+            self._ref = bytearray(b"\x01" * len(self.rows))
+            self._resident_bytes = sum(len(r) for r in self.rows if r)
+        else:
+            self._res.budget = budget
+        self._enforce_budget()
+
+    @property
+    def resident_code_bytes(self) -> int:
+        """Live encoded bytes held in memory: what the budget bounds."""
+        if self._res is None:
+            return sum(len(r) for r in self.rows if r)
+        return self._resident_bytes
+
+    @property
+    def code_bytes(self) -> int:
+        """Live encoded bytes, resident or spilled."""
+        return self.resident_code_bytes + self._spilled_payload
+
     def _append_payloads(self, payloads: List[bytes]) -> range:
         base = len(self.rows)
         self.rows.extend(payloads)
@@ -348,6 +378,7 @@ class _BytesRowStore(RowStore):
                     res.faults += len(ids)
                     res.fault_batches += 1
                     _C_ROW_FAULTS.add(len(ids))
+                    _C_FAULT_BYTES.add(sum(len(p) for p in payloads))
                 for j, i in enumerate(indices):
                     if out[j] is None and i not in dels:
                         out[j] = rows[i]
@@ -414,6 +445,7 @@ class _BytesRowStore(RowStore):
                 self._spilled_payload += ln
             res.spills += len(ids)
             _C_ROW_SPILLS.add(len(ids))
+            _C_SPILL_BYTES.add(sum(len(p) for p in payloads))
 
     def _repair_rows(self, ids: List[int]) -> None:
         """Rebuild corrupt spilled rows from the WAL via ``repair_fn``.
@@ -578,6 +610,7 @@ class _BytesRowStore(RowStore):
         self._spilled_payload = 0
         self.repair_fn = None
         self.repairs = 0
+        self._spill_path, self._spill_io = spill_path, spill_io
         self._restore_model(state["model"])
         res_state = state.get("residency")
         if res_state is not None:
@@ -687,6 +720,7 @@ class BlitzStore(RowStore):
                                      memory_budget=memory_budget,
                                      spill_path=spill_path,
                                      spill_io=spill_io)
+        self._spill_path, self._spill_io = spill_path, spill_io
         self.block_tuples = block_tuples
         # Durability hook, same contract as _BytesRowStore.repair_fn.
         self.repair_fn: Optional[Callable] = None
@@ -724,6 +758,30 @@ class BlitzStore(RowStore):
         bumps on ``install_codec`` (adaptive refit / migrate), stays put
         across merges/rewrites that keep the plan."""
         return self.table.current_version
+
+    # -- cold tier (DESIGN.md §6) ----------------------------------------
+    @property
+    def memory_budget(self) -> Optional[int]:
+        return self.table.memory_budget
+
+    def set_memory_budget(self, budget: int) -> None:
+        """Install the arena's cold tier, or move its budget on a live
+        store (a lowered budget spills down to it here)."""
+        if self.table.memory_budget is None:
+            self.table.set_memory_budget(budget, spill_path=self._spill_path,
+                                         spill_io=self._spill_io)
+        else:
+            self.table.set_memory_budget(budget)
+
+    @property
+    def resident_code_bytes(self) -> int:
+        """Live arena code bytes in memory (the overlay rides on top)."""
+        return self.table.resident_code_bytes
+
+    @property
+    def code_bytes(self) -> int:
+        """Live arena code bytes, resident or spilled."""
+        return self.table.code_bytes
 
     def migrate(self, limit: int = 1 << 12, resident_only: bool = True) -> int:
         """Re-encode up to ``limit`` stale escaped rows under the newest
@@ -967,6 +1025,7 @@ class BlitzStore(RowStore):
         self.table = CompressedTable.from_state(
             state["table"], spill_path=spill_path, spill_io=spill_io
         )
+        self._spill_path, self._spill_io = spill_path, spill_io
         flags = state["flags"]
         self.block_tuples = flags["block_tuples"]
         self.auto_merge = flags["auto_merge"]

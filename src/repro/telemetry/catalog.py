@@ -45,12 +45,19 @@ METRICS: Tuple[str, ...] = (
     # -- residency / out-of-core tier ------------------------------------
     "repro.residency.fault_in",
     "repro.residency.fault_in.blocks",
+    "repro.residency.fault_in.bytes",
     "repro.residency.fault_in.rows",
     "repro.residency.fault_in.rows.count",
     "repro.residency.spill",
     "repro.residency.spill.blocks",
+    "repro.residency.spill.bytes",
     "repro.residency.spill.rows",
     "repro.residency.spill.rows.count",
+    # a database memory limit's re-split among its tables (not a phase
+    # source: the spills a lowered budget forces are timed by their own
+    # spans inside it)
+    "repro.residency.split",
+    "repro.residency.split.count",
     # -- row stores -------------------------------------------------------
     "repro.store.merge",
     "repro.store.merge.events",

@@ -163,7 +163,7 @@ def test_drop_table_releases_files_and_fds(tmp_path):
     before = len(os.listdir("/proc/self/fd"))
     for i in range(3):
         root = str(tmp_path / f"db{i}")
-        db = Database(backend="blitzcrank", memory_budget=4 * 1024,
+        db = Database(backend="blitzcrank", memory_limit=4 * 1024,
                       durability=root)
         t = db.create_table(_customer_schema(), sample_rows=rows[:256])
         t.insert_many(rows[:256])
@@ -188,7 +188,7 @@ def test_disk_arena_context_manager(tmp_path):
 # -- recovery round trips -------------------------------------------------
 
 def _populated_durable_db(root, rows):
-    db = Database(backend="blitzcrank", memory_budget=4 * 1024,
+    db = Database(backend="blitzcrank", memory_limit=4 * 1024,
                   durability=root)
     t = db.create_table(_customer_schema(), sample_rows=rows[:256])
     t.insert_many(rows[:256])

@@ -327,7 +327,8 @@ class TestDbTableBudget:
             seed=11,
         )
         ref = Database(backend="blitzcrank", n_shards=2)
-        capped = Database(backend="blitzcrank", n_shards=2, memory_budget=2048)
+        capped = Database(backend="blitzcrank", n_shards=2,
+                          memory_limit=2048 * len(tpcc.TPCC_TABLES))
         for db in (ref, capped):
             for tname, schema in tpcc.TPCC_TABLES.items():
                 t = db.create_table(schema, sample_rows=pop[tname])
@@ -342,6 +343,157 @@ class TestDbTableBudget:
         s = capped.stats()
         assert s["spilled_bytes"] > 0
         assert s["residency"]["budget_bytes"] > 0
-        # per-shard split: each shard of a budgeted table carries a budget
-        shard = capped["order_line"].shards[0]
-        assert shard.table.memory_budget == 1024
+        # per-shard split: each shard carries half its table's share
+        for shard in capped["order_line"].shards:
+            assert shard.table.memory_budget == capped["order_line"].budget // 2
+
+
+def _tpcc_db(pop, **kwargs):
+    from repro.db import Database
+
+    db = Database(backend="blitzcrank", **kwargs)
+    for tname, schema in tpcc.TPCC_TABLES.items():
+        t = db.create_table(schema, sample_rows=pop[tname])
+        t.insert_many(pop[tname])
+    return db
+
+
+def _assert_within_limit(db):
+    """The limit's invariant: the whole database's live resident code
+    bytes are at most the limit plus each store's slack (and in fact at
+    most one byte a store over it: the floor of an empty table's part)."""
+    stores = [s for t in db for s in t.shards]
+    slack = sum(s.table._res.slack_bytes for s in stores)
+    assert db.resident_code_bytes <= db.memory_limit + slack
+    assert db.resident_code_bytes <= db.memory_limit + len(stores)
+
+
+def _new_lines(pop, o_base, n):
+    """``n`` fresh order lines: loaded lines under order ids from
+    ``o_base`` up."""
+    out = []
+    for j, r in enumerate(pop["order_line"][:n]):
+        out.append(dict(r, ol_o_id=o_base + j // 10, ol_number=1 + j % 10))
+    return out
+
+
+class TestDatabaseMemoryLimit:
+    """``Database(memory_limit=…)`` caps the whole database's resident
+    code bytes, split among tables of unequal size by their bytes."""
+
+    POP = dict(n_warehouses=1, districts_per_wh=2, customers_per_district=80,
+               n_items=150, orders_per_district=40, seed=5)
+
+    def _pair(self, n_shards=1, frac=0.25, **kwargs):
+        pop = tpcc.generate_tpcc(**self.POP)
+        ref = _tpcc_db(pop, n_shards=n_shards)
+        limit = int(frac * ref.code_bytes)
+        capped = _tpcc_db(pop, n_shards=n_shards, memory_limit=limit, **kwargs)
+        return pop, ref, capped
+
+    def test_limit_holds_as_one_table_grows_and_follows_bytes(self):
+        pop, ref, capped = self._pair()
+        _assert_within_limit(capped)
+        assert capped.stats()["spilled_bytes"] > 0
+        sizes = {t.name: t.code_bytes for t in capped}
+        assert len(set(sizes.values())) == len(sizes)  # unequal tables
+        before = {t.name: t.budget for t in capped}
+        # the split follows the tables' bytes
+        total = sum(sizes.values())
+        for t in capped:
+            assert abs(t.budget - capped.memory_limit * sizes[t.name] / total) <= 1
+        ol, cust = capped["order_line"], capped["customer"]
+        splits = 0
+        for step in range(12):
+            got = ol.insert_many(_new_lines(pop, 1000 + 100 * step, 150))
+            ref["order_line"].insert_many(_new_lines(pop, 1000 + 100 * step, 150))
+            assert len(got) == 150
+            _assert_within_limit(capped)
+            upd = [dict(r, c_balance=float(step)) for r in pop["customer"][:20]]
+            keys = [tpcc.TPCC_TABLES["customer"].key_of(r) for r in upd]
+            cust.update_many(keys, upd)
+            ref["customer"].update_many(keys, upd)
+            _assert_within_limit(capped)
+            assert cust.get_many(keys) == ref["customer"].get_many(keys)
+            _assert_within_limit(capped)
+            splits = max(splits, sum(1 for t in capped if t.budget != before[t.name]))
+        assert splits > 0
+        # order_line grew the most: its share of the limit rose, the others fell
+        assert ol.budget > before["order_line"]
+        assert capped["stock"].budget < before["stock"]
+        assert ol.code_bytes > sizes["order_line"]
+
+    @pytest.mark.parametrize("backend", ["numpy", "pallas"])
+    def test_reads_match_an_unlimited_database(self, backend):
+        pop, ref, capped = self._pair(n_shards=2)
+        tpcc.run_tpcc_mix(ref, 60, seed=3)
+        tpcc.run_tpcc_mix(capped, 60, seed=3)
+        _assert_within_limit(capped)
+        for tname in ("customer", "stock", "order_line"):
+            keys = [k for k, _ in ref[tname].scan()]
+            assert (capped[tname].get_many(keys, backend=backend)
+                    == ref[tname].get_many(keys, backend=backend))
+            _assert_within_limit(capped)
+        res = capped.stats()["residency"]
+        assert res["faults"] > 0 and res["memory_limit"] == capped.memory_limit
+
+    def test_a_table_budget_of_its_own_is_left_alone(self):
+        from repro.db import Database, TableSchema
+
+        pop = tpcc.generate_tpcc(**self.POP)
+        db = Database(backend="blitzcrank", memory_limit=1 << 14)
+        for tname, schema in tpcc.TPCC_TABLES.items():
+            own = 3000 if tname == "item" else None
+            db.create_table(schema, sample_rows=pop[tname],
+                            memory_budget=own).insert_many(pop[tname])
+        assert db["item"].budget == 3000
+        assert sum(t.budget for t in db if t.name != "item") <= (1 << 14) - 3000 + 1
+        _assert_within_limit(db)
+        with pytest.raises(ValueError):
+            db.create_table(TableSchema("item2", tpcc.ITEM_SCHEMA, "i_id"),
+                            sample_rows=pop["item"], memory_budget=1 << 14)
+        assert "item2" not in db
+
+    def test_checkpoint_and_reopen_keep_the_limit(self, tmp_path):
+        from repro.db import Database
+
+        pop = tpcc.generate_tpcc(**self.POP)
+        root = str(tmp_path / "db")
+        db = Database(backend="blitzcrank", memory_limit=1 << 14, durability=root)
+        for tname in ("customer", "order_line"):
+            t = db.create_table(tpcc.TPCC_TABLES[tname], sample_rows=pop[tname])
+            t.insert_many(pop[tname])
+        keys = {n: [k for k, _ in db[n].scan()] for n in db.table_names}
+        want = {n: db[n].get_many(k) for n, k in keys.items()}
+        db.close()
+        rdb = Database.open(root)
+        assert rdb.memory_limit == 1 << 14
+        _assert_within_limit(rdb)
+        for n, k in keys.items():
+            assert rdb[n].get_many(k) == want[n]
+        rdb["order_line"].insert_many(_new_lines(pop, 5000, 120))
+        _assert_within_limit(rdb)
+        rdb.close()
+
+    def test_a_budget_moves_on_a_live_store(self):
+        rows = GEN(1200, seed=9)
+        codec = TableCodec.fit(rows[:400], SCHEMA)
+        ref = CompressedTable(codec)
+        ref.extend(rows)
+        t = CompressedTable(codec, memory_budget=1 << 16)
+        t.extend(rows)
+        assert t.resident_code_bytes <= 1 << 16
+        t.set_memory_budget(1 << 12)  # lowered: spills down to it now
+        assert t.memory_budget == 1 << 12
+        assert t.resident_code_bytes <= 1 << 12
+        assert t.code_bytes == ref.code_bytes
+        every = list(range(len(rows)))
+        assert t.get_many(every) == ref.get_many(every)
+        t.set_memory_budget(1 << 20)  # raised: faults in only on demand
+        assert t.resident_code_bytes <= 1 << 12
+        t.get_many(every)
+        assert t.spilled_bytes == 0
+        with pytest.raises(ValueError):
+            t.set_memory_budget(0)
+        with pytest.raises(ValueError):
+            t.set_memory_budget(1 << 12, spill_path="elsewhere")
