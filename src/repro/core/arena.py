@@ -496,6 +496,12 @@ class ResidencyManager:
             int(self.config.slack_frac * self.budget),
         )
 
+    @property
+    def rewrite_codes(self) -> int:
+        """Arena codes, live or not, past which a store with dead residue
+        is rewritten: the budget plus the slack, in code units."""
+        return (self.budget + self.slack_bytes) // 2
+
     # -- the clock/second-chance sweep (shared by every store) -----------
     def sweep(
         self,

@@ -15,7 +15,7 @@ import contextlib
 import dataclasses
 import math
 import time
-from typing import TYPE_CHECKING, Any, Dict, List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -37,6 +37,7 @@ from .arena import (
     read_extents,
 )
 from .delayed import BlockDecoder
+from .mirror import ArenaMirror, MirroredCodes
 from .models import (
     BlockEncoder,
     CategoricalModel,
@@ -384,20 +385,26 @@ class TableCodec:
         indices: Sequence[int],
         backend: str = "numpy",
         template: int = 0,
+        device_codes: Optional[Callable[[], Any]] = None,
     ) -> List[Dict[str, Any]]:
         """Batch random-access decode from a CSR arena (compiled codecs only).
 
         Every indexed row must have been encoded by plan-family member
         ``template`` (its codes follow that member's fixed slot layout).
         ``backend`` is ``"numpy"`` or ``"pallas"`` (compiled on a TPU,
-        interpret mode on CPU, verified against numpy).
+        interpret mode on CPU, verified against numpy); ``device_codes``
+        hands the kernel path the same codes already in HBM
+        (:meth:`TablePlan.decode_select`).
         """
         plan = self.compile()
         if plan is None:
             raise RuntimeError(f"codec did not compile: {self._plan_reason}")
         plan = plan.family[template]
+        codes = checked_asarray(codes, np.uint16, where="decompress_rows codes")
+        if device_codes is not None:
+            codes = MirroredCodes(codes, device_codes)
         syms = plan.decode_select(
-            checked_asarray(codes, np.uint16, where="decompress_rows codes"),
+            codes,
             np.asarray(offsets, np.int64),
             np.asarray(indices, np.int64),
             backend=backend,
@@ -501,6 +508,11 @@ class CompressedTable:
         self.use_pallas = use_pallas
         self.arena = np.zeros(capacity_hint, dtype=np.uint16)
         self.used = 0
+        # HBM mirror of arena[:used] for the kernel decode (DESIGN.md §2.8),
+        # tied to the arena's generation: a rewrite or restore that
+        # replaces the arena bumps it, and the next decode re-uploads.
+        self._mirror = ArenaMirror()
+        self._arena_gen = 0
         self.n_blocks = 0
         self._offsets = np.zeros(1024, dtype=np.int64)
         self._fast = np.zeros(1023, dtype=bool)
@@ -753,7 +765,7 @@ class CompressedTable:
                 self._spill_until(res.target_codes)
             # Spilled/dead residue stays in the memory arena until a
             # rewrite; force one when physical footprint passes the slack.
-            if self._dead_codes and 2 * self.used > res.budget + res.slack_bytes:
+            if self._dead_codes and self.used > res.rewrite_codes:
                 self.rewrite()
             self._maybe_compact_disk()
         finally:
@@ -895,6 +907,19 @@ class CompressedTable:
             self.arena = new
         self.arena[self.used:need] = codes
         self.used = need
+
+    def _device_arena(self) -> Any:
+        """The arena's HBM mirror, synced to ``arena[:used]`` of this
+        generation.
+
+        Under a memory budget the arena breathes between rewrites, up to
+        the size that forces one (:attr:`ResidencyManager.rewrite_codes`);
+        the mirror is sized for that arena, so it does not step (and
+        recompile every decode bucket) each time the residue grows past a
+        fresh rewrite's.
+        """
+        most = 0 if self._res is None else self._res.rewrite_codes
+        return self._mirror.sync(self.arena, self.used, self._arena_gen, most)
 
     def _grow_index(self, n_new: int) -> None:
         need = self.n_blocks + n_new + 1
@@ -1265,12 +1290,14 @@ class CompressedTable:
                 for g in np.unique(route).tolist():
                     sel = fast_pos[route == g]
                     codec_v = self._codecs[g >> 8]
+                    be = self._resolve_backend(backend, sel.size, codec_v)
                     rows = codec_v.decompress_rows(
                         self.arena[:self.used],
                         self.block_offsets,
                         blks[sel],
-                        backend=self._resolve_backend(backend, sel.size, codec_v),
+                        backend=be,
                         template=g & 0xFF,
+                        device_codes=self._device_arena if be == "pallas" else None,
                     )
                     # blitzlint: waive[BL001] -- scatters scalar-decoded escape rows back into the batched result
                     for j, r in zip(sel.tolist(), rows):
@@ -1464,6 +1491,7 @@ class CompressedTable:
                 # the clock hand's position is meaningless after renumbering
                 res.hand = 0
             self.arena, self.used = arena, total
+            self._arena_gen += 1
             self._offsets, self._fast, self.n_blocks = offs, fast, nb
             self._plan_ver, self._tmpl = ver, tmpl
             self._zone_rebuild(blks, nb)
@@ -1598,6 +1626,7 @@ class CompressedTable:
         t.arena = np.zeros(max(arena.size, 1024), dtype=np.uint16)
         t.arena[:arena.size] = arena
         t.used = int(arena.size)
+        t._arena_gen += 1
         nb = len(state["block_rows"])
         cap = max(nb + 1, 1024)
         t._offsets = np.zeros(cap, dtype=np.int64)

@@ -46,6 +46,7 @@ from repro import sanitize, telemetry
 from . import vectorized
 from .casts import checked_astype
 from .coders import TOTAL, DiscreteCoder, UniformCoder
+from .mirror import MirroredCodes, upload_codes
 from .models import (
     _DIGIT10,
     CategoricalModel,
@@ -83,6 +84,7 @@ _C_ENCODE_ROWS = telemetry.counter("repro.core.encode.rows")
 _C_DECODE_ROWS = telemetry.counter("repro.core.decode.rows")
 _C_KERNEL_ROWS = telemetry.counter("repro.decode.kernel_rows")
 _C_KERNEL_ROWS_PADDED = telemetry.counter("repro.decode.kernel_rows_padded")
+_C_DEVICE_GATHER_ROWS = telemetry.counter("repro.decode.device_gather.rows")
 _C_PALLAS_PACK = telemetry.counter("repro.plan.pallas_pack.events")
 _C_TEMPLATE_ROWS = telemetry.counter("repro.plan.template_rows")
 
@@ -908,28 +910,34 @@ class TablePlan:
         rows: np.ndarray,
         backend: str = "numpy",
     ) -> np.ndarray:
-        """Random-access decode of selected tuples -> syms int64[R, S]."""
+        """Random-access decode of selected tuples -> syms int64[R, S].
+
+        ``codes`` is a host array, or :class:`~repro.core.mirror.MirroredCodes`:
+        the same codes beside their copy in HBM (a table's arena mirror),
+        which the kernel path gathers from without an upload.
+        """
         with telemetry.span("repro.core.decode"):
             if backend == "pallas":
                 out = self._decode_select_pallas(codes, offsets, rows)
             else:
-                out = vectorized.decode_select(codes, offsets, self.coders, rows,
+                host = codes.host if isinstance(codes, MirroredCodes) else codes
+                out = vectorized.decode_select(host, offsets, self.coders, rows,
                                                self.lam)
             _C_DECODE_ROWS.add(int(np.size(rows)))
         return out
 
     def _decode_select_pallas(
-        self, codes: np.ndarray, offsets: np.ndarray, rows: np.ndarray
+        self, codes: Any, offsets: np.ndarray, rows: np.ndarray
     ) -> np.ndarray:
         if not self.pallas_ok:
             raise PlanFallback("plan has conditional slots; Pallas ineligible")
         import jax.numpy as jnp
-        from repro.kernels.delayed_decode import BLOCK_T, delayed_decode
+        from repro.kernels.delayed_decode import BLOCK_T, delayed_decode_runs
         rows = np.asarray(rows, np.int64)
         if rows.size == 0:
             return np.zeros((0, self.S), np.int64)
-        # Prepare: everything before the result is awaited (gather, pad,
-        # host -> device copy, kernel dispatch).
+        # Prepare: everything before the result is awaited (each row's
+        # start and length, the codes' sync or upload, kernel dispatch).
         with telemetry.span("repro.decode.prepare"):
             # Pad the batch to a pow2 bucket so jax compiles one kernel per
             # bucket instead of one per distinct batch size.  The floor is
@@ -943,15 +951,18 @@ class TablePlan:
             # The kernel's slots are the family's (kernel_m_bits): at least
             # this member's; the slots past its own decode zeros, unread.
             tables, m_bits = self.pallas_tables()
-            starts = offsets[rows]
-            lens = offsets[rows + 1] - starts
-            cols = np.arange(len(m_bits))[None, :]
-            idx = starts[:, None] + np.minimum(cols, np.maximum(lens[:, None] - 1, 0))
-            idx = np.minimum(idx, max(codes.size - 1, 0))
-            dense = np.where(cols < lens[:, None], np.asarray(codes)[idx], 0).astype(
-                np.int32
-            )
-            out = delayed_decode(jnp.asarray(dense), tables, m_bits)
+            runs = np.empty((2, padded), np.int64)
+            runs[0] = offsets[rows]
+            runs[1] = offsets[rows + 1] - runs[0]
+            runs = checked_astype(runs, np.int32, where="decode_select runs")
+            # The chip gathers each row's run from codes in HBM: the
+            # caller's mirror, else its host codes uploaded in one copy.
+            if isinstance(codes, MirroredCodes):
+                dev = codes.device()
+                _C_DEVICE_GATHER_ROWS.add(n)
+            else:
+                dev = upload_codes(codes)
+            out = delayed_decode_runs(dev, jnp.asarray(runs), tables, m_bits)
         # Wait: the device's time left after dispatch plus the copy back.
         with telemetry.span("repro.decode.wait"):
             syms = np.asarray(out).astype(np.int64)[:n, :self.S]

@@ -17,10 +17,14 @@ slot chain across the whole tile:
   cursor advance is a masked add, and the cursor read is a row-wise
   one-hot reduction (no gathers anywhere).
 
-Inputs are the dense per-tuple layout produced by the host encoder
-(``codes_dense[T, S]``, left-justified).  Tables: float32[R, 7 * 128], the
-slot blocks laid out by :func:`slot_layout` — each slot at its own bucket
-count, so one wide slot does not pad the others.
+The kernel reads the dense per-tuple layout (``[T, S]`` int32,
+left-justified).  That block is built on the chip, in the same jitted
+program: :func:`gather_runs` cuts each requested row's code run out of a
+flat code array already in HBM (a table's arena mirror, DESIGN.md §2.8),
+so the host sends only each row's start and length.  Tables:
+float32[R, 7 * 128], the slot blocks laid out by :func:`slot_layout` —
+each slot at its own bucket count, so one wide slot does not pad the
+others.
 """
 
 from __future__ import annotations
@@ -159,39 +163,77 @@ def _delayed_kernel(
     jax.lax.fori_loop(0, S, slot, 0)
 
 
-def delayed_decode(
-    codes_dense: jax.Array,
+def gather_runs(codes: jax.Array, starts: jax.Array, lens: jax.Array,
+                n_slots: int) -> jax.Array:
+    """Flat codes + per-row ``(start, length)`` -> dense int32[T, n_slots].
+
+    ``dense[i, c] = codes[starts[i] + c]`` for ``c < lens[i]``, else 0.
+    Plain XLA, with no loop over rows: the codes are cut into pages of at
+    least ``n_slots`` codes, so a row's first ``n_slots`` codes lie in two
+    consecutive pages.  A row gather fetches both, and a barrel shift (one
+    select per bit of the run's offset in its page, widest first) moves
+    the run to column 0.  A run must end inside ``codes``.
+    """
+    page = max(LANES, 1 << (n_slots - 1).bit_length())
+    pages = jnp.pad(codes, (0, -codes.shape[0] % page)).reshape(-1, page)
+    first = starts // page
+    w = jnp.concatenate(
+        [jnp.take(pages, first, axis=0, mode="clip"),
+         jnp.take(pages, first + 1, axis=0, mode="clip")], axis=1,
+    ).astype(jnp.int32)
+    off = starts % page
+    k = page // 2
+    while k:
+        # after this bit the offset left is below k: keep what it can reach
+        width = n_slots + k - 1
+        w = jnp.where((off & k)[:, None] != 0, w[:, k:k + width], w[:, :width])
+        k //= 2
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, n_slots), 1)
+    return jnp.where(cols < lens[:, None], w, 0)
+
+
+def delayed_decode_runs(
+    codes: jax.Array,
+    runs: jax.Array,
     tables: jax.Array,
     m_bits: Tuple[int, ...],
 ) -> jax.Array:
-    """codes int32[T, S] + packed tables f32[R, 7*128] -> syms int32[T, S].
+    """Flat codes [C] + runs int32[2, T] (start, length per row) + packed
+    tables -> syms int32[T, len(m_bits)].
 
-    Thin telemetry shim over the jitted kernel: counts plan-cache
-    hits/misses per trace signature and books first-call (compile) time.
+    Thin telemetry shim over the jitted gather and kernel: counts
+    plan-cache hits/misses per trace signature and books first-call
+    (compile) time.  The signature holds the code array's length (a
+    mirror's capacity), so a compile that growth causes is counted.
     Interpret or compile is the platform's choice (:mod:`repro.device`).
     """
     interpret = device.interpret_default()
-    sig = (codes_dense.shape, tables.shape, tuple(m_bits), interpret)
+    sig = (codes.shape, str(codes.dtype), runs.shape, tables.shape,
+           tuple(m_bits), interpret)
     if sig in _SEEN_SIGS:
         _C_JIT_HIT.inc()
-        return _delayed_decode_jit(codes_dense, tables, m_bits, interpret)
+        return _delayed_decode_jit(codes, runs, tables, m_bits, interpret)
     _SEEN_SIGS.add(sig)
     _C_JIT_MISS.inc()
     with telemetry.span("repro.plan.compile.pallas_jit"):
-        return _delayed_decode_jit(codes_dense, tables, m_bits, interpret)
+        return _delayed_decode_jit(codes, runs, tables, m_bits, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("m_bits", "interpret"))
 def _delayed_decode_jit(
-    codes_dense: jax.Array,
+    codes: jax.Array,
+    runs: jax.Array,
     tables: jax.Array,
     m_bits: Tuple[int, ...],
     interpret: bool,
 ) -> jax.Array:
-    T, S = codes_dense.shape
+    S = len(m_bits)
+    T = runs.shape[1]
     n_blocks = -(-T // BLOCK_T)
     padded = n_blocks * BLOCK_T
-    codes_p = jnp.pad(codes_dense.astype(jnp.int32), ((0, padded - T), (0, 0)))
+    # rows past T are zero-length runs: all-zero code rows, sliced off
+    runs = jnp.pad(runs.astype(jnp.int32), ((0, 0), (0, padded - T)))
+    codes_p = gather_runs(codes, runs[0], runs[1], S)
     params = slot_params(m_bits)
     windows = tuple(sorted({p[3] for p in params}))
     out = pl.pallas_call(

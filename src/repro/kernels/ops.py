@@ -16,13 +16,12 @@ import numpy as np
 from repro.core.coders import DiscreteCoder, UniformCoder
 from . import ref as ref_lib
 from .alias_decode import alias_decode
-from .delayed_decode import LANES, N_FIELDS, TOTAL_BITS, delayed_decode, slot_layout
+from .delayed_decode import LANES, N_FIELDS, TOTAL_BITS, slot_layout
 from .flash_prefill import flash_prefill_attention
 from .kv_attention import kv_attention_int8
 
 __all__ = [
     "alias_decode",
-    "delayed_decode",
     "kv_attention_int8",
     "flash_prefill_attention",
     "pack_slot_tables",

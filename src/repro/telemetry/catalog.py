@@ -26,9 +26,12 @@ METRICS: Tuple[str, ...] = (
     "repro.core.encode.scalar",
     "repro.core.encode.scalar_block",
     # -- decode stages inside a core decode (not phase sources) ---------
+    "repro.decode.device_gather.rows",
     "repro.decode.kernel_rows",
     "repro.decode.kernel_rows_padded",
     "repro.decode.materialize",
+    "repro.decode.mirror.rebuilds",
+    "repro.decode.mirror.upload_bytes",
     "repro.decode.prepare",
     "repro.decode.wait",
     # -- plan compilation and kernel caches -----------------------------

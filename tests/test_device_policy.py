@@ -67,7 +67,7 @@ class TestInterpretDecision:
         import importlib
         import inspect
 
-        for entry in ("delayed_decode:delayed_decode",
+        for entry in ("delayed_decode:delayed_decode_runs",
                       "alias_decode:alias_decode",
                       "flash_prefill:flash_prefill_attention",
                       "kv_attention:kv_attention_int8"):
@@ -86,9 +86,9 @@ class TestInterpretDecision:
         seen = []
         real = dd._delayed_decode_jit
 
-        def spy(codes, tables, m_bits, interpret):
+        def spy(codes, runs, tables, m_bits, interpret):
             seen.append(interpret)
-            return real(codes, tables, m_bits, True)
+            return real(codes, runs, tables, m_bits, True)
 
         monkeypatch.setattr(dd, "_delayed_decode_jit", spy)
         rows = _rows(300)

@@ -10,6 +10,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro.core.coders import DiscreteCoder, quantize_freqs  # noqa: E402
 from repro.core.vectorized import encode_batch  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
+from repro.kernels.delayed_decode import delayed_decode_runs  # noqa: E402
 
 
 def _coder(rng, n):
@@ -34,6 +35,15 @@ class TestAliasDecodeKernel:
         np.testing.assert_array_equal(np.asarray(sym_r), sym_c)
 
 
+def _decode_csr(codes_csr, offsets, tables, mbits):
+    """The kernel on a CSR batch: the codes on the device, each row's run
+    by its offsets."""
+    offsets = np.asarray(offsets)
+    runs = np.stack([offsets[:-1], np.diff(offsets)]).astype(np.int32)
+    return delayed_decode_runs(jnp.asarray(codes_csr.astype(np.uint16)),
+                               jnp.asarray(runs), tables, mbits)
+
+
 class TestDelayedDecodeKernel:
     @pytest.mark.parametrize("n_slots,n_tuples", [(1, 64), (5, 300), (24, 130)])
     def test_sweep(self, n_slots, n_tuples):
@@ -45,8 +55,7 @@ class TestDelayedDecodeKernel:
         codes_csr, offsets = encode_batch(syms, coders)
         dense = ops.dense_codes(codes_csr.astype(np.int64), offsets, n_slots)
         tables, mbits = ops.pack_slot_tables(coders)
-        out_k = np.asarray(ops.delayed_decode(jnp.asarray(dense), tables,
-                                              mbits))
+        out_k = np.asarray(_decode_csr(codes_csr, offsets, tables, mbits))
         out_r = np.asarray(ref.delayed_decode_ref(jnp.asarray(dense), tables,
                                                   mbits))
         np.testing.assert_array_equal(out_r, syms)
@@ -60,9 +69,8 @@ class TestDelayedDecodeKernel:
         syms = np.zeros((50, 30), np.int64)
         syms[:, ::7] = 1
         codes_csr, offsets = encode_batch(syms, coders)
-        dense = ops.dense_codes(codes_csr.astype(np.int64), offsets, 30)
         tables, mbits = ops.pack_slot_tables(coders)
-        out = np.asarray(ops.delayed_decode(jnp.asarray(dense), tables, mbits))
+        out = np.asarray(_decode_csr(codes_csr, offsets, tables, mbits))
         np.testing.assert_array_equal(out, syms)
 
 
@@ -87,9 +95,9 @@ class TestDelayedDecodeTPUInterpret:
 
         t = tpcc_db[name].shards[0].table
         fast = t._fast[:t.n_blocks]
-        codes = t.arena[:t.used].astype(np.int64)
         # every member of the plan family, each on the blocks it encoded,
-        # at the family's shared kernel layout
+        # at the family's shared kernel layout, gathered on the device from
+        # the table's arena mirror
         for plan in t.codec.compile().family:
             rows = np.nonzero(fast & (t.block_templates == plan.template))[0][:256]
             assert rows.size
@@ -97,13 +105,11 @@ class TestDelayedDecodeTPUInterpret:
                                       backend="numpy")
             sel = np.concatenate([rows, np.full(256 - rows.size, rows[-1])])
             starts, ends = t.block_offsets[sel], t.block_offsets[sel + 1]
-            runs = np.concatenate([codes[a:b] for a, b in zip(starts, ends)])
+            runs = np.stack([starts, ends - starts]).astype(np.int32)
             tables, m_bits = plan.pallas_tables()
-            dense = ops.dense_codes(
-                runs, np.concatenate([[0], np.cumsum(ends - starts)]),
-                len(m_bits))
+            codes = t._device_arena()
             with pltpu.force_tpu_interpret_mode():
-                got = dd._delayed_decode_jit(jnp.asarray(dense), tables,
+                got = dd._delayed_decode_jit(codes, jnp.asarray(runs), tables,
                                              m_bits, False)
             np.testing.assert_array_equal(
                 np.asarray(got)[:rows.size, :plan.S], want)

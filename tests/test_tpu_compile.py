@@ -34,6 +34,7 @@ from repro.oltp import tpcc  # noqa: E402
 
 TABLES = sorted(tpcc.TPCC_TABLES)
 BUCKETS = (256, 4096)
+MIRROR_CODES = 1 << 24
 
 # Per-slot bucket bits (``plan.pallas_tables()[1]``) of one warehouse
 # loaded at spec cardinalities with seed 0: 10 districts of 3,000
@@ -110,12 +111,14 @@ def test_widest_slots_are_present(m_bits_by_table):
 
 def _compile(m_bits, rows, one_chip):
     _, n_rows = slot_layout(m_bits)
-    codes = jax.ShapeDtypeStruct((rows, len(m_bits)), jnp.int32,
-                                 sharding=one_chip)
+    # the arena mirror at a spec-scale stock table's capacity, and each
+    # row's (start, length) run in it
+    codes = jax.ShapeDtypeStruct((MIRROR_CODES,), jnp.uint16, sharding=one_chip)
+    runs = jax.ShapeDtypeStruct((2, rows), jnp.int32, sharding=one_chip)
     tables = jax.ShapeDtypeStruct((n_rows, N_FIELDS * LANES), jnp.float32,
                                   sharding=one_chip)
     compiled = _delayed_decode_jit.lower(
-        codes, tables, m_bits=m_bits, interpret=False).compile()
+        codes, runs, tables, m_bits=m_bits, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
