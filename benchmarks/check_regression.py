@@ -55,8 +55,6 @@ REQUIRED_BENCHES = [
     "granularity",
     "fastpath",
     "archive",
-    "framework",
-    "roofline",
 ]
 
 # Correctness booleans that hold at any scale: decode paths must stay

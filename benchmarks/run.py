@@ -1,4 +1,4 @@
-"""Benchmark harness: one module per paper table/figure + framework benches.
+"""Benchmark harness: one module per paper table/figure and per DESIGN.md section.
 
 Prints ``name,us_per_call,derived`` CSV lines (harness contract).  ``--full``
 uses paper-scale row counts; the default is CPU-quick.  ``--smoke`` runs
@@ -27,11 +27,10 @@ def main() -> None:
                             bench_batch_decode, bench_compression,
                             bench_db_tpcc, bench_entropy_coders,
                             bench_exec_engine, bench_fastpath,
-                            bench_framework, bench_granularity, bench_htap,
+                            bench_granularity, bench_htap,
                             bench_out_of_core, bench_recovery,
                             bench_sampling, bench_sanitize,
-                            bench_telemetry, bench_update_merge,
-                            roofline_report)
+                            bench_telemetry, bench_update_merge)
 
     if args.smoke:
         artifact.set_smoke(True)
@@ -54,8 +53,6 @@ def main() -> None:
         "granularity": bench_granularity,        # Fig 12
         "fastpath": bench_fastpath,              # Fig 13
         "archive": bench_archive,                # App F / Table 3
-        "framework": bench_framework,            # beyond-paper integrations
-        "roofline": roofline_report,             # §Dry-run/§Roofline artifacts
     }
     only = set(args.only.split(",")) if args.only else None
     print("name,us_per_call,derived")

@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# One Pallas kernel: delayed_decode.py (paper Algorithm 5, the batched
+# decode every engine read and scan runs on the chip).  ops.py packs its
+# tables; ref.py holds its pure-jnp oracle.

@@ -1,9 +1,10 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Host-side helpers for the delayed-decode kernel
+(:mod:`.delayed_decode`).
 
-Interpret or compile is the platform's choice (:mod:`repro.device`): the
-kernels compile for the chip on a TPU and run in the Pallas interpreter on
-the CPU.  Helpers convert host-side coder objects into the dense device
-table layout.
+They convert host coder objects into the kernel's packed device table
+layout (:func:`pack_slot_tables`, :func:`widen_table`,
+:func:`coder_m_bits`) and a CSR batch into dense left-justified codes
+(:func:`dense_codes`, the input of :func:`.ref.delayed_decode_ref`).
 """
 
 from __future__ import annotations
@@ -15,15 +16,9 @@ import numpy as np
 
 from repro.core.coders import DiscreteCoder, UniformCoder
 from . import ref as ref_lib
-from .alias_decode import alias_decode
 from .delayed_decode import LANES, N_FIELDS, TOTAL_BITS, slot_layout
-from .flash_prefill import flash_prefill_attention
-from .kv_attention import kv_attention_int8
 
 __all__ = [
-    "alias_decode",
-    "kv_attention_int8",
-    "flash_prefill_attention",
     "pack_slot_tables",
     "dense_codes",
 ]

@@ -1,9 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness contracts).
+"""Pure-jnp oracles for the delayed-decode kernel (its correctness
+contract) and the table packers it shares with :mod:`.ops`.
 
 These mirror the numpy host codecs in :mod:`repro.core` but stay inside jnp
 so they can be jit-compiled and compared against kernel outputs on any
-backend.  Tests sweep shapes/dtypes and assert allclose/exact-equal between
-``kernels.ops`` and these references.
+backend.  Tests sweep shapes and assert exact equality between the kernel
+(:mod:`.delayed_decode`) and these references.
 """
 
 from __future__ import annotations
@@ -121,39 +122,3 @@ def delayed_decode_ref(
         v_info = jnp.where(pending, v_info >> 16, v_info)
         v_size = jnp.where(pending, v_size >> 16, v_size)
     return jnp.stack(out, axis=1)
-
-
-def twolevel_dequant_ref(
-    bucket: jax.Array, digit: jax.Array, vmin: float, p: float, G: int
-) -> jax.Array:
-    """Two-level numeric reconstruction (§4.2): v = vmin + (i*G + j + .5)p."""
-    q = bucket.astype(jnp.float32) * G + digit.astype(jnp.float32)
-    return vmin + (q + 0.5) * p
-
-
-def kv_attention_int8_ref(
-    q: jax.Array,
-    kq: jax.Array,
-    vq: jax.Array,
-    k_scale: jax.Array,
-    v_scale: jax.Array,
-    length: jax.Array,
-) -> jax.Array:
-    """Decode attention over int8-quantized KV with per-(token, head) scales.
-
-    q: [B, H, D] (bf16/f32); kq/vq: int8[B, S, K, D];
-    k_scale/v_scale: f32[B, S, K]; length: [] valid cache length.
-    Returns [B, H, D] float32.
-    """
-    B, H, D = q.shape
-    _, S, K, _ = kq.shape
-    G = H // K
-    kf = kq.astype(jnp.float32) * k_scale[..., None]
-    vf = vq.astype(jnp.float32) * v_scale[..., None]
-    qf = q.reshape(B, K, G, D).astype(jnp.float32) * (D ** -0.5)
-    s = jnp.einsum("bkgd,bskd->bkgs", qf, kf)
-    valid = jnp.arange(S) < length
-    s = jnp.where(valid[None, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bskd->bkgd", p, vf)
-    return o.reshape(B, H, D)

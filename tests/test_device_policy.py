@@ -61,21 +61,16 @@ class TestInterpretDecision:
         assert device.interpret_default() is False
 
     def test_interpreter_refused_on_tpu(self):
-        """No kernel entry point takes ``interpret`` from its caller, so
-        nothing can ask for the interpreter on a TPU: the flag comes from
-        the platform policy alone."""
-        import importlib
+        """The kernel's entry point does not take ``interpret`` from its
+        caller, so nothing can ask for the interpreter on a TPU: the flag
+        comes from the platform policy alone."""
         import inspect
 
-        for entry in ("delayed_decode:delayed_decode_runs",
-                      "alias_decode:alias_decode",
-                      "flash_prefill:flash_prefill_attention",
-                      "kv_attention:kv_attention_int8"):
-            module, name = entry.split(":")
-            mod = importlib.import_module(f"repro.kernels.{module}")
-            params = inspect.signature(getattr(mod, name)).parameters
-            assert "interpret" not in params, entry
-            assert "device.interpret_default()" in inspect.getsource(mod), entry
+        from repro.kernels import delayed_decode as dd
+
+        params = inspect.signature(dd.delayed_decode_runs).parameters
+        assert "interpret" not in params
+        assert "device.interpret_default()" in inspect.getsource(dd)
 
     def test_engine_decode_takes_the_platform_choice(self, on_platform,
                                                      monkeypatch):
@@ -131,6 +126,78 @@ class TestDowngradeCounter:
         from repro.telemetry.catalog import METRICS
 
         assert "repro.plan.pallas_downgrade" in METRICS
+
+
+# (backend argument, store's use_pallas, plan can run the kernel) ->
+# (decode backend, counted as a pallas->numpy downgrade), on the CPU.
+# The auto row (None, None, True) is numpy here because the CPU
+# interprets; TestBackendDecisionTable.test_auto_mode_on_tpu takes it
+# on a TPU.
+DECISIONS = {
+    (None, None, True): ("numpy", False),
+    (None, None, False): ("numpy", False),
+    (None, True, True): ("pallas", False),
+    (None, True, False): ("numpy", True),
+    (None, False, True): ("numpy", False),
+    (None, False, False): ("numpy", False),
+    ("numpy", None, True): ("numpy", False),
+    ("numpy", None, False): ("numpy", False),
+    ("numpy", True, True): ("numpy", False),
+    ("numpy", True, False): ("numpy", False),
+    ("numpy", False, True): ("numpy", False),
+    ("numpy", False, False): ("numpy", False),
+    ("pallas", None, True): ("pallas", False),
+    ("pallas", None, False): ("numpy", True),
+    ("pallas", True, True): ("pallas", False),
+    ("pallas", True, False): ("numpy", True),
+    ("pallas", False, True): ("pallas", False),
+    ("pallas", False, False): ("numpy", True),
+}
+
+
+class TestBackendDecisionTable:
+    """Host or device is decided today by three inputs: the ``backend``
+    argument, the store's ``use_pallas`` and the auto mode's
+    ``PALLAS_MIN_ROWS`` on a TPU.  ``CompressedTable._resolve_backend``
+    combines them; these cases pin what it returns, so that folding the
+    three into one policy changes this table on purpose only."""
+
+    @pytest.fixture(scope="class")
+    def codec(self):
+        return TableCodec.fit(_rows(400, seed=2), SCHEMA, sample=256)
+
+    @pytest.mark.parametrize("eligible", [True, False],
+                             ids=["eligible", "conditional"])
+    @pytest.mark.parametrize("use_pallas", [None, True, False],
+                             ids=["store_auto", "store_pallas", "store_numpy"])
+    @pytest.mark.parametrize("backend", [None, "numpy", "pallas"],
+                             ids=["arg_none", "arg_numpy", "arg_pallas"])
+    def test_decision(self, codec, monkeypatch, backend, use_pallas,
+                      eligible):
+        plan = codec.compile()
+        assert plan.pallas_ok
+        if not eligible:
+            # a plan with conditional slots cannot run the kernel
+            monkeypatch.setattr(plan, "pallas_ok", False)
+        table = CompressedTable(codec, use_pallas=use_pallas)
+        ctr = telemetry.counter("repro.plan.pallas_downgrade")
+        n0 = ctr.value
+        got = table._resolve_backend(backend, CompressedTable.PALLAS_MIN_ROWS)
+        want, counted = DECISIONS[(backend, use_pallas, eligible)]
+        assert got == want
+        assert ctr.value == n0 + int(counted)
+
+    @pytest.mark.parametrize("below", [True, False],
+                             ids=["below_min_rows", "at_min_rows"])
+    def test_auto_mode_on_tpu(self, codec, on_platform, below):
+        """With no argument and no store choice, a TPU takes the kernel
+        from ``PALLAS_MIN_ROWS`` rows on."""
+        assert codec.compile().pallas_ok
+        on_platform("tpu")
+        table = CompressedTable(codec)
+        n = CompressedTable.PALLAS_MIN_ROWS - int(below)
+        assert table._resolve_backend(None, n) == ("numpy" if below
+                                                   else "pallas")
 
 
 def _restore_cache_dir(prev):

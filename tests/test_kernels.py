@@ -1,5 +1,5 @@
-"""Per-kernel shape/dtype sweeps: pallas_call (interpret) vs ref.py oracle
-vs the numpy host codec (three-way agreement)."""
+"""Delayed-decode kernel sweeps: pallas_call (interpret) vs the ref.py
+oracle vs the numpy host codec (three-way agreement)."""
 
 import numpy as np
 import pytest
@@ -18,23 +18,6 @@ def _coder(rng, n):
     return DiscreteCoder(quantize_freqs(w * 1e7))
 
 
-class TestAliasDecodeKernel:
-    @pytest.mark.parametrize("n_symbols", [1, 2, 37, 255, 1000])
-    @pytest.mark.parametrize("n_codes", [17, 1024, 4097])
-    def test_sweep(self, n_symbols, n_codes):
-        rng = np.random.default_rng(n_symbols * 1000 + n_codes)
-        dc = _coder(rng, n_symbols)
-        table, m = ref.pack_tables(dc)
-        codes = rng.integers(0, 65536, n_codes).astype(np.int32)
-        sym_k, a_k, k_k = ops.alias_decode(jnp.asarray(codes), table, m)
-        sym_r, a_r, k_r = ref.alias_decode_ref(jnp.asarray(codes), table, m)
-        sym_c, a_c, k_c = dc.inv_translate_batch(codes)
-        np.testing.assert_array_equal(np.asarray(sym_k), sym_c)
-        np.testing.assert_array_equal(np.asarray(a_k), a_c)
-        np.testing.assert_array_equal(np.asarray(k_k), k_c)
-        np.testing.assert_array_equal(np.asarray(sym_r), sym_c)
-
-
 def _decode_csr(codes_csr, offsets, tables, mbits):
     """The kernel on a CSR batch: the codes on the device, each row's run
     by its offsets."""
@@ -42,6 +25,30 @@ def _decode_csr(codes_csr, offsets, tables, mbits):
     runs = np.stack([offsets[:-1], np.diff(offsets)]).astype(np.int32)
     return delayed_decode_runs(jnp.asarray(codes_csr.astype(np.uint16)),
                                jnp.asarray(runs), tables, mbits)
+
+
+class TestAliasLookupInDelayedDecode:
+    """The alias lookup (Algorithm 6) as the engine runs it: inside the
+    delayed-decode kernel, one slot per row.  ``alias_decode_ref``, the
+    lookup's oracle inside ``delayed_decode_ref``, is held to the host
+    coder on the same grid."""
+
+    @pytest.mark.parametrize("n_symbols", [1, 2, 37, 255, 1000])
+    @pytest.mark.parametrize("n_tuples", [17, 1024, 4097])
+    def test_sweep(self, n_symbols, n_tuples):
+        rng = np.random.default_rng(n_symbols * 1000 + n_tuples)
+        dc = _coder(rng, n_symbols)
+        syms = rng.integers(0, dc.tables.n_symbols, (n_tuples, 1))
+        codes_csr, offsets = encode_batch(syms, [dc])
+        tables, mbits = ops.pack_slot_tables([dc])
+        out = np.asarray(_decode_csr(codes_csr, offsets, tables, mbits))
+        np.testing.assert_array_equal(out, syms)
+
+        table, m = ref.pack_tables(dc)
+        codes = rng.integers(0, 65536, n_tuples).astype(np.int32)
+        got = ref.alias_decode_ref(jnp.asarray(codes), table, m)
+        for g, want in zip(got, dc.inv_translate_batch(codes)):
+            np.testing.assert_array_equal(np.asarray(g), want)
 
 
 class TestDelayedDecodeKernel:
@@ -74,6 +81,43 @@ class TestDelayedDecodeKernel:
         np.testing.assert_array_equal(out, syms)
 
 
+def _add_history_and_new_order(db, pop):
+    """TPC-C's history and new_order tables (rev. 5.11 clause 1.3), which
+    the program's TPC-C database leaves out: a loaded payment per
+    customer (4.3.3.1) and the newest third of each district's orders
+    undelivered."""
+    from repro.core import ColumnSpec
+    from repro.db import TableSchema
+
+    rng = np.random.default_rng(1)
+    history = [{
+        "h_id": i + 1, "h_c_id": c["c_id"], "h_c_d_id": c["c_d_id"],
+        "h_c_w_id": c["c_w_id"], "h_d_id": c["c_d_id"],
+        "h_w_id": c["c_w_id"], "h_date": 19800,
+        "h_amount": float(np.round(rng.uniform(1.0, 5000.0), 2)),
+        "h_data": f"WH-{c['c_w_id']:03d}    DIST-{c['c_d_id']:02d}",
+    } for i, c in enumerate(pop["customer"])]
+    n_orders = max(o["o_id"] for o in pop["orders"])
+    new_order = [{"no_o_id": o["o_id"], "no_d_id": o["o_d_id"],
+                  "no_w_id": o["o_w_id"]}
+                 for o in pop["orders"] if o["o_id"] > n_orders * 2 // 3]
+    schemas = [
+        (TableSchema("history", [
+            ColumnSpec("h_id", "int", growth=8.0), ColumnSpec("h_c_id", "int"),
+            ColumnSpec("h_c_d_id", "int"), ColumnSpec("h_c_w_id", "int"),
+            ColumnSpec("h_d_id", "int"), ColumnSpec("h_w_id", "int"),
+            ColumnSpec("h_date", "int", growth=0.01),
+            ColumnSpec("h_amount", "float", precision=0.01, growth=2.0),
+            ColumnSpec("h_data", "str")], "h_id"), history),
+        (TableSchema("new_order", [
+            ColumnSpec("no_o_id", "int", growth=8.0),
+            ColumnSpec("no_d_id", "int"), ColumnSpec("no_w_id", "int")],
+            ("no_w_id", "no_d_id", "no_o_id")), new_order),
+    ]
+    for schema, rows in schemas:
+        db.create_table(schema, sample_rows=rows).insert_many(rows)
+
+
 class TestDelayedDecodeTPUInterpret:
     """The compiled-kernel path (``interpret=False``) under Pallas's TPU
     interpreter, which models SMEM/VMEM and the kernel's scratch, on the
@@ -85,10 +129,13 @@ class TestDelayedDecodeTPUInterpret:
         pop = tpcc.generate_tpcc(n_warehouses=1, districts_per_wh=10,
                                  customers_per_district=30, n_items=300,
                                  orders_per_district=30, seed=0)
-        return tpcc.build_tpcc_database(population=pop)[0]
+        db = tpcc.build_tpcc_database(population=pop)[0]
+        _add_history_and_new_order(db, pop)
+        return db
 
-    @pytest.mark.parametrize("name", ["customer", "district", "item",
-                                      "order_line", "orders", "stock"])
+    @pytest.mark.parametrize("name", ["customer", "district", "history",
+                                      "item", "new_order", "order_line",
+                                      "orders", "stock"])
     def test_matches_numpy(self, tpcc_db, name):
         from jax.experimental.pallas import tpu as pltpu
         from repro.kernels import delayed_decode as dd
@@ -113,90 +160,3 @@ class TestDelayedDecodeTPUInterpret:
                                              m_bits, False)
             np.testing.assert_array_equal(
                 np.asarray(got)[:rows.size, :plan.S], want)
-
-
-class TestKVAttentionKernel:
-    @pytest.mark.parametrize("B,S,K,G,D", [
-        (1, 256, 1, 1, 64), (2, 1024, 4, 3, 64), (2, 512, 8, 2, 128),
-    ])
-    @pytest.mark.parametrize("qdtype", [np.float32, jnp.bfloat16])
-    def test_sweep(self, B, S, K, G, D, qdtype):
-        rng = np.random.default_rng(B * S + K)
-        H = K * G
-        q = rng.normal(size=(B, H, D)).astype(np.float32)
-        kf = rng.normal(size=(B, S, K, D)).astype(np.float32)
-        vf = rng.normal(size=(B, S, K, D)).astype(np.float32)
-        ks = np.abs(kf).max(-1) / 127.0 + 1e-8
-        vs = np.abs(vf).max(-1) / 127.0 + 1e-8
-        kq = np.clip(np.round(kf / ks[..., None]), -127, 127).astype(np.int8)
-        vq = np.clip(np.round(vf / vs[..., None]), -127, 127).astype(np.int8)
-        L = S - S // 3
-        qj = jnp.asarray(q).astype(qdtype)
-        out_k = np.asarray(ops.kv_attention_int8(
-            qj, jnp.asarray(kq), jnp.asarray(ks), jnp.asarray(vq),
-            jnp.asarray(vs), L, chunk=min(512, S)))
-        out_r = np.asarray(ref.kv_attention_int8_ref(
-            jnp.asarray(qj, jnp.float32), jnp.asarray(kq), jnp.asarray(vq),
-            jnp.asarray(ks), jnp.asarray(vs), jnp.asarray(L)))
-        tol = 5e-2 if qdtype == jnp.bfloat16 else 1e-4
-        np.testing.assert_allclose(out_k, out_r, atol=tol, rtol=tol)
-
-    def test_quantization_error_bounded(self):
-        """int8 semantic quantization keeps attention output close to fp."""
-        rng = np.random.default_rng(3)
-        B, S, K, G, D = 1, 512, 2, 2, 64
-        q = rng.normal(size=(B, K * G, D)).astype(np.float32)
-        kf = rng.normal(size=(B, S, K, D)).astype(np.float32)
-        vf = rng.normal(size=(B, S, K, D)).astype(np.float32)
-        ks = np.abs(kf).max(-1) / 127.0 + 1e-8
-        vs = np.abs(vf).max(-1) / 127.0 + 1e-8
-        kq = np.clip(np.round(kf / ks[..., None]), -127, 127).astype(np.int8)
-        vq = np.clip(np.round(vf / vs[..., None]), -127, 127).astype(np.int8)
-        out_q = np.asarray(ops.kv_attention_int8(
-            jnp.asarray(q), jnp.asarray(kq), jnp.asarray(ks),
-            jnp.asarray(vq), jnp.asarray(vs), S))
-        # fp reference attention (unquantized)
-        import jax
-        qf = q.reshape(B, K, G, D) * (D ** -0.5)
-        s = np.einsum("bkgd,bskd->bkgs", qf, kf)
-        p = np.asarray(jax.nn.softmax(jnp.asarray(s), axis=-1))
-        out_f = np.einsum("bkgs,bskd->bkgd", p, vf).reshape(B, K * G, D)
-        assert np.abs(out_q - out_f).max() < 0.05
-
-
-class TestFlashPrefillKernel:
-    """Fused prefill attention (§Perf cell-3 structural fix) vs the XLA
-    chunked-attention reference across shapes, masks and dtypes."""
-
-    @pytest.mark.parametrize("B,Sq,Sk,K,G,D,causal,win", [
-        (2, 128, 128, 2, 3, 64, True, 0),
-        (1, 200, 200, 4, 1, 32, True, 48),
-        (2, 96, 160, 2, 2, 64, False, 0),
-        (1, 64, 64, 1, 8, 128, True, 0),
-    ])
-    def test_matches_chunked_attention(self, B, Sq, Sk, K, G, D, causal, win):
-        import jax
-        from repro.kernels.flash_prefill import flash_prefill_attention
-        from repro.models.layers import AttnSpec, chunked_attention
-        rng = np.random.default_rng(B * Sq + Sk)
-        H = K * G
-        q = jnp.asarray(rng.normal(size=(B, Sq, H, D)), jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, Sk, K, D)), jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, Sk, K, D)), jnp.float32)
-        out_k = flash_prefill_attention(q, k, v, causal=causal, window=win,
-                                        q_block=64, kv_chunk=64)
-        spec = AttnSpec(causal=causal, q_block=64, kv_chunk=64)
-        out_r = chunked_attention(q, k, v, jnp.arange(Sq), spec,
-                                  window=(win if win else None))
-        np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
-                                   atol=2e-5, rtol=2e-5)
-
-    def test_bf16_io(self):
-        from repro.kernels.flash_prefill import flash_prefill_attention
-        rng = np.random.default_rng(0)
-        q = jnp.asarray(rng.normal(size=(1, 64, 4, 32)), jnp.bfloat16)
-        k = jnp.asarray(rng.normal(size=(1, 64, 2, 32)), jnp.bfloat16)
-        v = jnp.asarray(rng.normal(size=(1, 64, 2, 32)), jnp.bfloat16)
-        out = flash_prefill_attention(q, k, v, q_block=32, kv_chunk=32)
-        assert out.dtype == jnp.bfloat16
-        assert np.isfinite(np.asarray(out, np.float32)).all()

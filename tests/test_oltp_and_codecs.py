@@ -1,4 +1,4 @@
-"""OLTP stores (paper §6/§7 setting) + tensor codecs + HLO analyzer."""
+"""OLTP stores (paper §6/§7 setting)."""
 
 import numpy as np
 import pytest
@@ -79,91 +79,3 @@ class TestStores:
         for i in keys:
             fp.read_modify_write(int(i), lambda r: r.update(ol_quantity=1))
         assert fp.hits / (fp.hits + fp.misses) > 0.3
-
-
-class TestTensorCodecs:
-    def test_lossless16_exact(self):
-        import jax.numpy as jnp
-        from repro.tensor.codec import fit_codec
-        rng = np.random.default_rng(0)
-        w = np.asarray(jnp.asarray(rng.normal(0, 0.02, 4096),
-                                   jnp.bfloat16)).view(np.uint16)
-        codec = fit_codec(w, "lossless16")
-        ct = codec.encode(w)
-        assert (codec.decode(ct) == w).all()
-        assert ct.ratio() > 1.2
-
-    def test_twolevel_precision_bound(self):
-        from repro.tensor.codec import fit_codec
-        rng = np.random.default_rng(1)
-        x = rng.normal(0, 1.0, 8192).astype(np.float32)
-        codec = fit_codec(x, "twolevel", precision=1e-3)
-        back = codec.decode(codec.encode(x))
-        assert np.abs(back - x).max() <= 5e-4 + 1e-9
-
-    def test_twolevel_outliers_exact(self):
-        from repro.tensor.codec import fit_codec
-        x = np.concatenate([np.random.default_rng(0).normal(0, 1, 1024),
-                            [1e9, -1e9]]).astype(np.float32)
-        codec = fit_codec(x[:1024], "twolevel", precision=1e-3)
-        back = codec.decode(codec.encode(x))
-        assert back[-2] == np.float32(1e9) and back[-1] == np.float32(-1e9)
-
-    def test_kv_store_page_access(self):
-        from repro.tensor.kv_cache import CompressedKVStore
-        rng = np.random.default_rng(2)
-        store = CompressedKVStore(page_tokens=16)
-        k = rng.normal(0, 1, (16, 4, 32)).astype(np.float32)
-        v = rng.normal(0, 1, (16, 4, 32)).astype(np.float32)
-        store.put(0, 0, k, v)
-        k2, v2 = store.get(0, 0)
-        assert np.abs(k2 - k).max() < 0.2
-        assert store.nbytes < k.nbytes + v.nbytes
-
-
-class TestHloAnalyzer:
-    def test_scan_trip_counts(self):
-        import jax
-        import jax.numpy as jnp
-        from repro.analysis.hlo import analyze_hlo
-        x = jax.ShapeDtypeStruct((64, 128), jnp.float32)
-        w = jax.ShapeDtypeStruct((128, 128), jnp.float32)
-
-        def f(x, w):
-            def body(c, _):
-                return jnp.tanh(c @ w), None
-            y, _ = jax.lax.scan(body, x, None, length=8)
-            return y
-        st = analyze_hlo(jax.jit(f).lower(x, w).compile().as_text(), 1)
-        assert st.flops / (2 * 64 * 128 * 128) == pytest.approx(8.0)
-
-    def test_grad_scan_counts_remat(self):
-        import jax
-        import jax.numpy as jnp
-        from repro.analysis.hlo import analyze_hlo
-        x = jax.ShapeDtypeStruct((64, 128), jnp.float32)
-        w = jax.ShapeDtypeStruct((128, 128), jnp.float32)
-
-        def f(x, w):
-            def body(c, _):
-                return jnp.tanh(c @ w), None
-            y, _ = jax.lax.scan(jax.checkpoint(body), x, None, length=8)
-            return jnp.sum(y * y)
-        st = analyze_hlo(
-            jax.jit(jax.grad(f, argnums=1)).lower(x, w).compile().as_text(), 1)
-        # fwd 8 + remat 8 + bwd 2x8 = 32 matmuls
-        assert st.flops / (2 * 64 * 128 * 128) == pytest.approx(32.0)
-
-    def test_collective_parse(self):
-        from repro.analysis.hlo import analyze_hlo
-        hlo = """
-HloModule test, entry_computation_layout={()->f32[8]{0}}
-
-ENTRY %main () -> f32[8] {
-  %p = f32[8]{0} parameter(0)
-  ROOT %ar = f32[8]{0} all-reduce(%p), replica_groups=[1,4]<=[4], to_apply=%add
-}
-"""
-        st = analyze_hlo(hlo, 4)
-        assert st.collective_counts.get("all-reduce") == 1
-        assert st.collective_wire_bytes == pytest.approx(2 * 3 / 4 * 32)
